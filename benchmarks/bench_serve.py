@@ -476,24 +476,31 @@ def _bench_poisson(X, y, lmax, cfg, n, p):
 def _bench_restart(X, y, lmax, cfg, n, p):
     """Restart-on-same-cache-dir: with the persistent compilation cache
     wired, a restarted server's warmup writes ZERO new cache entries —
-    every compile replays from disk."""
+    every compile replays from disk. The cache is
+    ``$JAX_COMPILATION_CACHE_DIR`` when set, else a fixed directory in
+    the checkout, emptied first so the first life starts cold."""
     import glob
     import os
     import shutil
-    import tempfile
 
     from repro import Problem, Scalar
-    from repro.core.server import open_server
+    from repro.core.server import CHECKOUT_CACHE_DIR, open_server
 
     cfg_srv = _serve_cfg(cfg)
     prob = Problem(X=X, y=y)
     lams = [f * lmax for f in (0.30, 0.28, 0.26, 0.24)]
-    cache_dir = tempfile.mkdtemp(prefix="saif-serve-cache-")
+    own_dir = not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        CHECKOUT_CACHE_DIR, "bench_serve_restart")
+    if own_dir:
+        shutil.rmtree(cache_dir, ignore_errors=True)
 
     def cache_files():
         return len([f for f in glob.glob(
             os.path.join(cache_dir, "**"), recursive=True)
             if os.path.isfile(f)])
+
+    files_before = cache_files()
 
     def life():
         """One server lifetime: open on the cache dir, serve the warmup
@@ -512,7 +519,7 @@ def _bench_restart(X, y, lmax, cfg, n, p):
         jax.clear_caches()                   # cold first life
         t_first = life()
         files_first = cache_files()
-        assert files_first > 0, (
+        assert files_first > files_before or not own_dir, (
             "persistent compilation cache wrote nothing — the restart "
             "contract cannot hold")
         jax.clear_caches()                   # "restart": lose the
@@ -537,8 +544,8 @@ def _bench_restart(X, y, lmax, cfg, n, p):
             f"first life ({t_first:.2f}s) — disk replay is not working")
         return row
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-        shutil.rmtree(cache_dir, ignore_errors=True)
+        if own_dir:
+            jax.config.update("jax_compilation_cache_dir", None)
 
 
 if __name__ == "__main__":

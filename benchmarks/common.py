@@ -9,45 +9,10 @@ import numpy as np
 
 jax.config.update("jax_enable_x64", True)   # paper-grade duality gaps
 
-
-def simulation_data(n=100, p=5000, seed=0):
-    """Paper Sec 5.1.1: X ~ U[-10,10], 20% active betas in [-1,1], N(0,1)."""
-    rng = np.random.default_rng(seed)
-    X = rng.uniform(-10, 10, (n, p))
-    beta = np.zeros(p)
-    idx = rng.choice(p, int(0.2 * p), replace=False)
-    beta[idx] = rng.uniform(-1, 1, len(idx))
-    y = X @ beta + rng.normal(0, 1, n)
-    return X, y, beta
-
-
-def breast_cancer_shaped(seed=1):
-    """Shape/conditioning-matched synthetic for the 295x8141 microarray set:
-    standardized correlated gaussian features, +-1 labels (paper regresses
-    the binary label with least squares)."""
-    rng = np.random.default_rng(seed)
-    n, p = 295, 8141
-    # low-rank + noise covariance mimics gene co-expression structure
-    k = 30
-    F = rng.normal(size=(p, k)) / np.sqrt(k)
-    Z = rng.normal(size=(n, k))
-    X = Z @ F.T + 0.7 * rng.normal(size=(n, p))
-    X = (X - X.mean(0)) / (X.std(0) + 1e-12)
-    w = np.zeros(p)
-    w[rng.choice(p, 60, replace=False)] = rng.normal(size=60)
-    y = np.sign(X @ w + 0.5 * rng.normal(size=n))
-    y[y == 0] = 1.0
-    return X, y
-
-
-def logistic_shaped(n, p, seed=2, k=40):
-    rng = np.random.default_rng(seed)
-    X = rng.normal(size=(n, p))
-    w = np.zeros(p)
-    w[rng.choice(p, k, replace=False)] = rng.uniform(-2, 2, k)
-    y = np.sign(X @ w + 0.3 * rng.normal(size=n))
-    y[y == 0] = 1.0
-    return X, y
+# the data protocols live in benchmarks/data.py (no jax import, no x64
+# switch), so float32 chip runs can share them
+from benchmarks.data import (breast_cancer_shaped,  # noqa: E402,F401
+                             logistic_shaped, simulation_data)
 
 
 def timed(fn: Callable, *, warmup: bool = True) -> Dict[str, float]:

@@ -61,6 +61,7 @@ import numpy as np
 # serving.py can isinstance-dispatch on api.Update / api.Select
 from repro.core.online import Update
 from repro.core.select import Select, SelectionReport
+from repro.core.serving import highest_matmul_precision
 
 __all__ = [
     "Problem", "Session", "open_session",
@@ -271,8 +272,8 @@ class CompileStats(NamedTuple):
     """Unified view of every solver-core jit cache (DESIGN.md §9).
 
     ``serial``/``fleet``/``group`` are the process-wide cache sizes of
-    ``_saif_jit`` / ``_saif_batch_jit`` / ``_gsaif_jit`` (-1 if the jit
-    internals moved); ``since_open`` is the total's delta since the
+    ``_saif_jit`` / ``_saif_batch_jit`` / ``_gsaif_jit``;
+    ``since_open`` is the total's delta since the
     session opened — the number every serving assertion watches: across
     any request stream it must equal the number of *distinct static
     keys*, never the number of requests.
@@ -287,14 +288,11 @@ class CompileStats(NamedTuple):
 
 def _cache_size(mod_name: str, fn_name: str) -> int:
     """Cache size of one engine's jit, 0 if the module was never imported
-    (an un-imported engine has compiled nothing), -1 if unreadable."""
+    (an un-imported engine has compiled nothing)."""
     mod = sys.modules.get(mod_name)
     if mod is None:
         return 0
-    try:
-        return int(getattr(mod, fn_name)._cache_size())
-    except Exception:       # pragma: no cover - jit internals moved
-        return -1
+    return int(getattr(mod, fn_name)._cache_size())
 
 
 def _engine_cache_sizes() -> Tuple[int, int, int]:
@@ -307,10 +305,7 @@ def unified_compile_count() -> int:
     """Total solver-core compilations alive in this process: the serial,
     fleet and group engine caches in one number (supersedes reading the
     three per-module counters separately)."""
-    sizes = _engine_cache_sizes()
-    if min(sizes) < 0:
-        return -1
-    return sum(sizes)
+    return sum(_engine_cache_sizes())
 
 
 # ---------------------------------------------------------------------------
@@ -339,6 +334,7 @@ class Session:
     for default (cold) requests they are bitwise the legacy results.
     """
 
+    @highest_matmul_precision
     def __init__(self, problem: Problem, config=None, **kwargs):
         kw = session_kwargs(**kwargs)
         self.problem = problem
@@ -473,7 +469,9 @@ class Session:
             else:
                 self._prep = None
         try:
-            self.screen_backend = resolve_backend(cfg.screen_backend)
+            self.screen_backend = resolve_backend(
+                cfg.screen_backend,
+                None if self._prep is None else self._prep.X.dtype)
         except ValueError:
             # fleet-only screen modes (the opt-in "matmul" shared-X fast
             # path, §8) resolve through the batch policy; serial requests
@@ -490,6 +488,7 @@ class Session:
     # the one entry point
     # ------------------------------------------------------------------
 
+    @highest_matmul_precision
     def solve(self, request):
         """Serve one request; see :class:`Scalar` / :class:`Path` /
         :class:`Fleet` / :class:`CV` for the workload shapes and the
@@ -556,9 +555,8 @@ class Session:
     def compile_stats(self) -> CompileStats:
         """Unified compile accounting; see :class:`CompileStats`."""
         serial, fleet, grp = _engine_cache_sizes()
-        total = -1 if min(serial, fleet, grp) < 0 else serial + fleet + grp
-        base = getattr(self, "_compiles0", 0)
-        since = (total - base) if (total >= 0 and base >= 0) else -1
+        total = serial + fleet + grp
+        since = total - getattr(self, "_compiles0", 0)
         return CompileStats(serial=serial, fleet=fleet, group=grp,
                             total=total, since_open=since,
                             requests=self._requests)
@@ -737,7 +735,7 @@ class Session:
             design = self._sharded_design()
             prep = self._sharded_path_prep(design)
             pr, warm, k = run_path(
-                prep, lams, self.config,
+                prep, lams, self._sharded_config(),
                 make_screen=lambda h: self._memo_sharded_screen(design, h),
                 segment_len=self._segment_len,
                 warm0=self._sharded_warm if req.warm else None,
@@ -790,7 +788,7 @@ class Session:
             results.append(res)
         self._gwarm = cur
         n1 = group_compile_count()
-        n_comp = max(n1 - n0, 0) if (n0 >= 0 and n1 >= 0) else None
+        n_comp = max(n1 - n0, 0)
         return GroupPathResult(lams=lams_np,
                                betas=[r.beta for r in results],
                                results=results, n_compilations=n_comp)
@@ -814,7 +812,8 @@ class Session:
                     "on the replicated path for now (DESIGN.md §8)")
             from repro.distributed.saif_sharded import fleet_solve_sharded
             return fleet_solve_sharded(
-                self.problem.X, req.Y, req.lams, self.mesh, self.config,
+                self.problem.X, req.Y, req.lams, self.mesh,
+                self._sharded_config(),
                 design=self._sharded_fleet_design(req.Y),
                 screen_cache=self._sharded_fleet_screens)
         from repro.core.batch import fleet_solve
@@ -891,6 +890,22 @@ class Session:
                 "sharded=True needs a device mesh: open_session(problem, "
                 "config, mesh=mesh)")
 
+    def _sharded_config(self):
+        """The config of feature-sharded solves: a Mosaic kernel cannot be
+        partitioned across the mesh, so the replicated inner burst stays
+        on XLA there — ``auto`` becomes ``gram`` (least squares) or
+        ``jnp``, and an explicit ``pallas`` is refused (DESIGN.md §5)."""
+        cfg = self.config
+        if cfg.inner_backend == "pallas":
+            raise ValueError(
+                "inner_backend='pallas' does not compose with sharded=True: "
+                "the VMEM kernel cannot be partitioned across the mesh")
+        if cfg.inner_backend != "auto":
+            return cfg
+        return dataclasses.replace(
+            cfg, inner_backend=("gram" if cfg.loss == "least_squares"
+                                else "jnp"))
+
     def _sharded_design(self):
         self._require_mesh()
         if self._sharded is None:
@@ -934,7 +949,8 @@ class Session:
             # (and refreshing) the sharded warm state
             from repro.core.path import run_path
             pr, wstate, k = run_path(
-                self._sharded_path_prep(design), [lam], self.config,
+                self._sharded_path_prep(design), [lam],
+                self._sharded_config(),
                 make_screen=lambda h: self._memo_sharded_screen(design, h),
                 segment_len=self._segment_len,
                 warm0=self._sharded_warm, k_max0=self._sharded_warm_k)
@@ -944,8 +960,8 @@ class Session:
         from repro.distributed.saif_sharded import solve_scalar_sharded
         y = self._y if isinstance(self.penalty, FusedPenalty) \
             else self.problem.y
-        return solve_scalar_sharded(None, y, lam, self.mesh, self.config,
-                                    design=design,
+        return solve_scalar_sharded(None, y, lam, self.mesh,
+                                    self._sharded_config(), design=design,
                                     screen_cache=self._sharded_screen_memo,
                                     prep=self._sharded_path_prep(design))
 

@@ -68,7 +68,7 @@ from repro.core.saif import (SaifConfig, SaifResult, add_batch_size_static,
                              default_capacity)
 from repro.core.screen_backend import (SCREEN_RULES, BatchScreenFn,
                                        ScreenOut, ScreenRule,
-                                       make_batch_screen,
+                                       fleet_col_norms, make_batch_screen,
                                        make_batch_screen_fast,
                                        resolve_batch_screen,
                                        resolve_screen_rule)
@@ -368,10 +368,10 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                 # strong-rule recruiting: only actual KKT violators
                 keep = keep & (out.cand_score >= 1.0)
             keep = jnp.cumprod(keep.astype(jnp.int32), axis=1).astype(bool)
-            # progress guarantee, per problem (DESIGN.md §2)
-            stuck = gap <= 100.0 * eps
-            keep = keep.at[:, 0].set(
-                keep[:, 0] | (stuck & jnp.isfinite(out.cand_score[:, 0])))
+            # progress guarantee, per problem (the serial engine's rule,
+            # DESIGN.md §2): every candidate the ball cannot rule out, and
+            # the top-scoring one
+            keep = keep | _stuck_recruits(out, col_norm, r_eff, gap, eps)
             adding = do_add & ~add_done
             aset = aset_lib.add_features_batch(aset, out.cand_idx,
                                                keep & adding[:, None])
@@ -843,9 +843,7 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             if screen_rule.add_bound == "point":
                 keep = keep & (out.cand_score >= 1.0)
             keep = jnp.cumprod(keep.astype(jnp.int32), axis=1).astype(bool)
-            stuck = gap <= 100.0 * eps
-            keep = keep.at[:, 0].set(
-                keep[:, 0] | (stuck & jnp.isfinite(out.cand_score[:, 0])))
+            keep = keep | _stuck_recruits(out, col_norm, r_eff, gap, eps)
             adding = do_add & ~add_done
             aset = _add_features_fast(aset, out.cand_idx,
                                       keep & adding[:, None])
@@ -935,11 +933,8 @@ def saif_batch_compile_count() -> int:
     """Distinct fleet-engine compilations alive in this process (the
     bitwise ``_saif_batch_jit`` cache plus the fast-parity
     ``_saif_batch_fast_jit`` cache)."""
-    try:
-        return (int(_saif_batch_jit._cache_size()) +
-                int(_saif_batch_fast_jit._cache_size()))
-    except Exception:       # pragma: no cover - jit internals moved
-        return -1
+    return (int(_saif_batch_jit._cache_size()) +
+            int(_saif_batch_fast_jit._cache_size()))
 
 
 class FleetPrep(NamedTuple):
@@ -1111,25 +1106,46 @@ def _delta0s(prep: FleetPrep, lams, config: SaifConfig):
             for lam, mx in zip(lams, prep.c0_max)]
 
 
+def _stuck_recruits(out: ScreenOut, col_norm, r_eff, gap, eps):
+    """(B, h) forced recruits of the fleet's progress guarantee: for each
+    problem whose sub-problem is solved to near-target accuracy, every
+    candidate its ball cannot rule out plus its top-scoring candidate
+    (the serial engine's rule, ``saif._saif_jit``)."""
+    stuck = (gap <= 100.0 * eps)[:, None]
+    fin = jnp.isfinite(out.cand_score)
+    cn = jnp.take_along_axis(fleet_col_norms(col_norm, fin.shape[0]),
+                             out.cand_idx, axis=1)
+    ub_c = out.cand_score + cn * jnp.reshape(r_eff, (-1, 1))
+    top = jnp.arange(fin.shape[1])[None, :] == 0
+    return stuck & fin & ((ub_c >= 1.0) | top)
+
+
 def resolve_batch_inner(config: SaifConfig, n: int, k_max: int,
-                        b: int) -> str:
+                        b: int, dtype=None) -> str:
     """Fleet inner-backend policy: the serial policy with the
-    double-buffered fleet VMEM budget gating the pallas kernel."""
+    double-buffered fleet VMEM budget gating the pallas kernel (and no
+    float64 fleet or x64 mode on the TPU kernel, as in the serial
+    policy)."""
+    from repro.core.screen_backend import mosaic_refuses
     from repro.kernels.cm.cm import cm_vmem_ok
 
     name, loss_name = config.inner_backend, config.loss
     from repro.core.inner_backend import GRAM_CROSSOVER
     if name == "auto":
+        if (jax.default_backend() == "tpu" and cm_vmem_ok(n, k_max, batch=b)
+                and not mosaic_refuses(dtype)):
+            return "pallas"
         if loss_name == "least_squares" and GRAM_CROSSOVER * n >= k_max:
             return "gram"
-        if jax.default_backend() == "tpu" and cm_vmem_ok(n, k_max, batch=b):
-            return "pallas"
         return "jnp"
     if name not in ("jnp", "gram", "pallas"):
         raise ValueError(f"unknown inner backend {name!r}")
     if name == "gram" and loss_name != "least_squares":
         raise ValueError("inner_backend='gram' requires "
                          "loss='least_squares'")
+    if name == "pallas" and mosaic_refuses(dtype):
+        raise ValueError("inner_backend='pallas' on TPU needs a float32 "
+                         "fleet with jax_enable_x64 off (Mosaic has no f64)")
     if name == "pallas" and not cm_vmem_ok(n, k_max, batch=b):
         raise ValueError(
             f"inner_backend='pallas': a fleet of {b} {n}x{k_max} active "
@@ -1182,7 +1198,8 @@ def fleet_solve(X, Y, lam, config: SaifConfig = SaifConfig(),
     lams = [float(v) for v in jax.device_get(lam_arr)]
     rule = resolve_screen_rule(config.screen_rule)
     use_seq = config.use_seq_ball and W is None and rule.use_seq_ball
-    backend = resolve_batch_screen(config.screen_backend, b=b, p=p_eff)
+    backend = resolve_batch_screen(config.screen_backend, b=b, p=p_eff,
+                                   dtype=X.dtype)
     # parity="fast" dispatch (DESIGN.md §11): the lockstep engine is
     # least-squares only (its inner burst is the batched Gram sweep) and
     # a custom screen_fn owns its own scores — both fall back to the
@@ -1236,7 +1253,7 @@ def fleet_solve(X, Y, lam, config: SaifConfig = SaifConfig(),
                 screen_dtype=config.screen_dtype,
                 has_weights=W is not None, screen_rule=rule))
         else:
-            inner = resolve_batch_inner(config, n_eff, k_max, b)
+            inner = resolve_batch_inner(config, n_eff, k_max, b, X.dtype)
             carry = cold_inner_carry_batch(b, k_max, X.dtype, backend=inner)
             res = _fault_seam("fleet", lambda: _saif_batch_jit(
                 X, Y, W_arg, prep.col_norm, prep.c0, lam_arr,

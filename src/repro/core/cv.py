@@ -90,7 +90,7 @@ def cv_solve(X, y, lams: Sequence[float], n_folds: int = 5,
     n_compile0 = saif_batch_compile_count()
 
     prep = prepare_fleet(X, Y, config, weights=W)
-    backend = resolve_batch_screen(config.screen_backend)
+    backend = resolve_batch_screen(config.screen_backend, dtype=X.dtype)
     # grid-max static h over the whole K x L fleet family; per-(fold,
     # lambda) batch sizes and tolerances stay traced — the path-engine
     # trick (§4), fleet edition
@@ -117,7 +117,7 @@ def cv_solve(X, y, lams: Sequence[float], n_folds: int = 5,
             cold_idx = jnp.pad(cold_idx, ((0, 0), (0, pad)))
             cold_beta = jnp.pad(cold_beta, ((0, 0), (0, pad)))
             cold_mask = jnp.pad(cold_mask, ((0, 0), (0, pad)))
-        inner = resolve_batch_inner(config, n, k_max, K)
+        inner = resolve_batch_inner(config, n, k_max, K, X.dtype)
         warm = None
         results: List[SaifResult] = []
         for li, lam in enumerate(lams_np):
@@ -177,8 +177,7 @@ def cv_solve(X, y, lams: Sequence[float], n_folds: int = 5,
         beta_best = best_result.beta
 
     n_compile1 = saif_batch_compile_count()
-    n_comp = (max(n_compile1 - n_compile0, 0)
-              if n_compile0 >= 0 and n_compile1 >= 0 else None)
+    n_comp = max(n_compile1 - n_compile0, 0)
     return CVPathResult(
         lams=lams_np, cv_mean=cv_mean, cv_se=cv_se, best_lam=best_lam,
         beta=beta_best, best_result=best_result,

@@ -341,8 +341,8 @@ def polish_unpen(loss: Loss, x: jax.Array, y: jax.Array, z: jax.Array,
 
     def step(_, carry):
         b, z = carry
-        g = jnp.dot(x, loss.grad(z, y))
-        H = jnp.dot(x * x, loss.hess(z, y))
+        g = jnp.sum(x * loss.grad(z, y))
+        H = jnp.sum(x * x * loss.hess(z, y))
         d = jnp.clip(g / jnp.maximum(H, 1e-30), -lim, lim)
         return b - d, z - d * x
 

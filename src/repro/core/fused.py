@@ -220,8 +220,10 @@ def transform_design_device(X, tree: TreeTransform,
     if schedule is None:
         schedule = build_schedule(tree)
     if backend == "auto":
+        from repro.core.screen_backend import mosaic_refuses
         backend = ("pallas" if schedule.is_chain
-                   and jax.default_backend() == "tpu" else "scan")
+                   and jax.default_backend() == "tpu"
+                   and not mosaic_refuses(jnp.asarray(X).dtype) else "scan")
     if backend == "pallas":
         if not schedule.is_chain:
             raise ValueError("the Pallas fused transform is the chain "

@@ -250,10 +250,7 @@ def group_compile_count() -> int:
     (gsize, h, k_max) is lambda-independent, so a session serving many
     group requests must move this counter exactly once — asserted in
     tests/test_api.py."""
-    try:
-        return int(_gsaif_jit._cache_size())
-    except Exception:       # pragma: no cover - jit internals moved
-        return -1
+    return int(_gsaif_jit._cache_size())
 
 
 class GroupPrep(NamedTuple):

@@ -466,20 +466,27 @@ GRAM_CROSSOVER = 4.0
 
 
 def resolve_inner_backend(name: str, loss_name: str, n: int,
-                          k_max: int) -> str:
-    """Inner-backend selection policy (DESIGN.md §6): explicit name wins;
-    ``auto`` picks the covariance-update engine whenever the loss gradient
-    is linear (least squares) and the active capacity is not >> n, the
-    fused Pallas kernel on TPU when the block fits VMEM, and the jnp
-    reference path elsewhere (off-TPU the kernel would run interpreted —
-    a correctness oracle, strictly slower than XLA)."""
+                          k_max: int, dtype=None) -> str:
+    """Inner-backend selection policy (DESIGN.md §6): explicit name wins.
+    ``auto`` picks the fused Pallas kernel on TPU whenever the block fits
+    VMEM — the burst is a sequential coordinate loop, which the kernel
+    runs inside VMEM while XLA pays a while-loop iteration per coordinate
+    (on a v5e the gram burst's loop dominated the outer step). Elsewhere
+    (and for problems Mosaic refuses: float64 or x64 mode, where an
+    explicit ``pallas`` raises) it picks the covariance-update engine
+    whenever the loss gradient is linear (least squares) and the active
+    capacity is not >> n, and the jnp reference path otherwise (off-TPU
+    the kernel would run interpreted — a correctness oracle, strictly
+    slower than XLA)."""
+    from repro.core.screen_backend import mosaic_refuses
     from repro.kernels.cm.cm import cm_vmem_ok
 
     if name == "auto":
+        if (jax.default_backend() == "tpu" and cm_vmem_ok(n, k_max)
+                and not mosaic_refuses(dtype)):
+            return "pallas"
         if loss_name == "least_squares" and GRAM_CROSSOVER * n >= k_max:
             return "gram"
-        if jax.default_backend() == "tpu" and cm_vmem_ok(n, k_max):
-            return "pallas"
         return "jnp"
     if name not in ("jnp", "gram", "pallas"):
         raise ValueError(f"unknown inner backend {name!r}")
@@ -487,6 +494,10 @@ def resolve_inner_backend(name: str, loss_name: str, n: int,
         raise ValueError("inner_backend='gram' requires loss='least_squares'"
                          " (covariance updates need a linear gradient); use"
                          " 'jnp' or 'pallas'")
+    if name == "pallas" and mosaic_refuses(dtype):
+        raise ValueError("inner_backend='pallas' on TPU needs a float32 "
+                         "problem with jax_enable_x64 off (Mosaic has no "
+                         "f64); use float32 or 'gram'/'jnp'")
     if name == "pallas" and not cm_vmem_ok(n, k_max):
         raise ValueError(
             f"inner_backend='pallas': a {n}x{k_max} active block exceeds "

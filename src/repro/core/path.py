@@ -107,7 +107,7 @@ def cold_start(prep: PathState, h0: int, k: int,
                                         config.unpen_idx, prep.b0,
                                         prep.X.dtype)
     inner = resolve_inner_backend(config.inner_backend, config.loss,
-                                  prep.n_true or n, k)
+                                  prep.n_true or n, k, prep.X.dtype)
     return (idx, beta, jnp.arange(k) < n_init,
             cold_inner_carry(k, prep.X.dtype, backend=inner))
 
@@ -202,7 +202,7 @@ def seq_warm_entry(prep: PathState, warm: WarmState, k_max: int,
     n, _ = prep.X.shape
     k_out = max(int(k_max), int(warm[0].shape[0]))
     name = resolve_inner_backend(config.inner_backend, config.loss,
-                                 prep.n_true or n, k_out)
+                                 prep.n_true or n, k_out, prep.X.dtype)
     idx, vals, mask, carry = grow_warm(warm, k_out, name)
     X = prep.X
     p_true = prep.p_true or X.shape[1]
@@ -257,7 +257,7 @@ def run_path(prep: PathState, lams: Sequence[float],
     # gap from the previous grid point is already tiny)
     use_seq = config.use_seq_ball and unpen is None and rule.use_seq_ball
     lams_np = np.asarray(sorted([float(l) for l in lams], reverse=True))
-    backend = resolve_backend(config.screen_backend)
+    backend = resolve_backend(config.screen_backend, prep.X.dtype)
     n_compile0 = saif_jit_compile_count()
 
     # One static signature for the whole path: grid-max h (pow2-bucketed).
@@ -279,7 +279,7 @@ def run_path(prep: PathState, lams: Sequence[float],
 
     def inner_name(k: int) -> str:
         return resolve_inner_backend(config.inner_backend, config.loss,
-                                     n_true, k)
+                                     n_true, k, prep.X.dtype)
 
     def run_lam(lam: float, h_lam: int, warm: WarmState) -> SaifResult:
         delta0 = config.delta0 if config.delta0 is not None else \
@@ -331,8 +331,7 @@ def run_path(prep: PathState, lams: Sequence[float],
 
     betas = [r.beta for r in results]
     n_compile1 = saif_jit_compile_count()
-    n_comp = (max(n_compile1 - n_compile0, 0)
-              if n_compile0 >= 0 and n_compile1 >= 0 else None)
+    n_comp = max(n_compile1 - n_compile0, 0)
     return (SaifPathResult(lams=lams_np, betas=betas, results=results,
                            n_compilations=n_comp),
             warm, k_max)

@@ -427,10 +427,18 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                 # Progress guarantee (TPU adaptation, DESIGN.md §2): when the
                 # sub-problem is already solved to near-target accuracy but no
                 # candidate passes the violation test (radius floored by
-                # arithmetic precision), force-recruit the top-scoring
-                # feature. ADDing extra features is always safe (Thm 1a) —
-                # it can only cost compute, never correctness.
+                # arithmetic precision), force-recruit every candidate the
+                # ball cannot rule out, and the top-scoring feature. The
+                # survivor blocking the ADD stop can sit below the top score
+                # (a larger column norm widens its bound); recruiting only
+                # the top score, which DEL then proves zero and evicts,
+                # cycles until max_outer. ADDing extra features is always
+                # safe (Thm 1a) — it can only cost compute, never
+                # correctness.
                 stuck = gap <= 100.0 * eps
+                ub_c = out.cand_score + jnp.take(col_norm, out.cand_idx) * r_eff
+                keep = keep | (stuck & jnp.isfinite(out.cand_score)
+                               & (ub_c >= 1.0))
                 keep = keep.at[0].set(
                     keep[0] | (stuck & jnp.isfinite(out.cand_score[0])))
                 return (aset_lib.add_features(aset, out.cand_idx, keep),
@@ -530,18 +538,12 @@ def saif_jit_compile_count() -> int:
     assert on deltas of this counter (acceptance: O(log p) compilations
     per lambda path; exactly 1 per fleet).
     """
-    try:
-        total = int(_saif_jit._cache_size())
-    except Exception:       # pragma: no cover - older/newer jit internals
-        return -1
-    try:
-        import sys
-        batch_mod = sys.modules.get("repro.core.batch")
-        if batch_mod is not None:
-            total += int(batch_mod._saif_batch_jit._cache_size())
-            total += int(batch_mod._saif_batch_fast_jit._cache_size())
-    except Exception:       # pragma: no cover
-        pass
+    import sys
+    total = int(_saif_jit._cache_size())
+    batch_mod = sys.modules.get("repro.core.batch")
+    if batch_mod is not None:
+        total += int(batch_mod._saif_batch_jit._cache_size())
+        total += int(batch_mod._saif_batch_fast_jit._cache_size())
     return total
 
 
@@ -663,7 +665,7 @@ def solve_scalar(prep: PathState, lam: float,
     k_max = config.k_max or default_capacity(h, p_true)
     delta0 = config.delta0 if config.delta0 is not None else \
         min(max(lam / lam_max, 1e-3), 1.0)
-    backend = resolve_backend(config.screen_backend)
+    backend = resolve_backend(config.screen_backend, X.dtype)
 
     # Initial active set: top-h' by |X^T f'(0)| (Algorithm 1 line 1),
     # or a warm start from a neighbouring lambda (Sec 5.3 path mode).
@@ -708,7 +710,7 @@ def solve_scalar(prep: PathState, lam: float,
             init_beta = jnp.pad(init_beta, (0, pad))
         # capacity growth can move the auto crossover (DESIGN.md §6)
         inner = resolve_inner_backend(config.inner_backend, config.loss,
-                                      n_true, k_max)
+                                      n_true, k_max, X.dtype)
         carry = cold_inner_carry(k_max, X.dtype, backend=inner)
         # the engine dispatch routes through the fault-injection seam
         # (repro.runtime.inject) — a single None-check when disarmed

@@ -453,7 +453,7 @@ MATMUL_MIN_BP = 32_768
 
 
 def resolve_batch_screen(name: str, *, b: Optional[int] = None,
-                         p: Optional[int] = None) -> str:
+                         p: Optional[int] = None, dtype=None) -> str:
     """Fleet screen policy (DESIGN.md §8).
 
     ``matmul`` (the shared-X one-gemm screen, ulp-grade vs serial scans)
@@ -464,7 +464,7 @@ def resolve_batch_screen(name: str, *, b: Optional[int] = None,
     B*p=8k CI shape; mechanism measured in the section comment above),
     so an informed call (``b``/``p`` known) downgrades it to ``jnp``.
     Name-only calls (legacy/tests that construct a screen directly) keep
-    honoring the explicit opt-in.
+    honoring the explicit opt-in. ``dtype`` feeds :func:`resolve_backend`.
     """
     if name == "matmul":
         if jax.default_backend() != "cpu":
@@ -472,15 +472,35 @@ def resolve_batch_screen(name: str, *, b: Optional[int] = None,
         if b is None or p is None:          # uninformed call: honor opt-in
             return name
         return name if b * p >= MATMUL_MIN_BP else "jnp"
-    return resolve_backend(name)
+    return resolve_backend(name, dtype)
 
 
-def resolve_backend(name: str) -> str:
+def mosaic_refuses(dtype) -> bool:
+    """True when a compiled (TPU) Pallas kernel cannot serve a problem of
+    ``dtype``: Mosaic has no float64, and the kernels compile with
+    ``jax_enable_x64`` off (:func:`repro.kernels.screen.screen.refuse_x64`).
+    Off TPU the kernels run interpreted and take anything; ``dtype=None``
+    is an uninformed call (only the x64 mode counts)."""
+    if jax.default_backend() != "tpu":
+        return False
+    return bool(jax.config.jax_enable_x64) or (
+        dtype is not None and jnp.dtype(dtype) == jnp.float64)
+
+
+def resolve_backend(name: str, dtype=None) -> str:
     """Backend-selection policy (DESIGN.md §3): explicit name wins; ``auto``
     compiles the fused kernels on TPU and keeps the XLA path elsewhere
-    (the interpreter would be strictly slower than the jnp matvec)."""
+    (the interpreter would be strictly slower than the jnp matvec). A
+    float64 design (or x64 mode) on TPU stays on ``jnp`` under ``auto``,
+    and an explicit ``pallas`` for it raises: Mosaic has no f64, and a
+    silent down-cast would change the certificate."""
     if name == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return ("pallas" if jax.default_backend() == "tpu"
+                and not mosaic_refuses(dtype) else "jnp")
     if name not in ("jnp", "pallas"):
         raise ValueError(f"unknown screen backend {name!r}")
+    if name == "pallas" and mosaic_refuses(dtype):
+        raise ValueError("screen_backend='pallas' on TPU needs a float32 "
+                         "design with jax_enable_x64 off (Mosaic has no "
+                         "f64); use float32 or 'jnp'")
     return name

@@ -143,7 +143,7 @@ def select_solve(X, y, req: Select,
         beta = best.beta
 
     c1 = saif_batch_compile_count() + saif_jit_compile_count()
-    n_comp = max(c1 - c0, 0) if c0 >= 0 and c1 >= 0 else None
+    n_comp = max(c1 - c0, 0)
     return SelectionReport(
         lams=cv.lams, cv_mean=cv.cv_mean, cv_se=cv.cv_se,
         lam_min=lam_min, lam_1se=lam_1se, lam=lam, rule=str(req.rule),

@@ -32,7 +32,10 @@ economics, not numerics:
 * **Restart warmth** — with ``ServerConfig.cache_dir`` set, JAX's
   persistent compilation cache is enabled (min-compile-time/entry-size
   thresholds zeroed) so a restarted server replays its compiles from
-  disk: zero cold-start compilations on the second life.
+  disk: zero cold-start compilations on the second life. A
+  ``JAX_COMPILATION_CACHE_DIR`` in the environment takes precedence:
+  nothing then sets another directory in code
+  (:func:`enable_compile_cache`).
 
 Module scope imports only stdlib + numpy — ``from repro import
 open_server`` keeps the lazy-surface contract; jax and the engines load
@@ -43,6 +46,8 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import itertools
+import os
+import pathlib
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
@@ -50,7 +55,12 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 __all__ = ["ServerConfig", "ServerStats", "ServingFuture", "Server",
-           "open_server"]
+           "open_server", "enable_compile_cache", "CHECKOUT_CACHE_DIR"]
+
+# fixed in-checkout compile-cache directory (ignored by git): the path is
+# part of the cache key, so it must not move between runs
+CHECKOUT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +213,7 @@ def _problem_digest(problem, *, design_only: bool = False) -> str:
         a = np.ascontiguousarray(np.asarray(arr))
         h.update(str(a.shape).encode())
         h.update(str(a.dtype).encode())
-        h.update(a.tobytes())
+        h.update(a.reshape(-1).view(np.uint8))      # no bytes copy
     h.update(problem.loss.encode())
     h.update(repr(problem.penalty).encode())
     return h.hexdigest()
@@ -259,7 +269,7 @@ class Server:
         self._opts = opts
         self._guard = guard
         if self.config.cache_dir:
-            _enable_persistent_cache(self.config.cache_dir)
+            enable_compile_cache(self.config.cache_dir)
         self._cond = threading.Condition()
         self._queues: Dict[tuple, List[_Entry]] = {}
         self._inflight = 0
@@ -381,7 +391,7 @@ class Server:
 
     def _bucket_key(self, problem, request) -> tuple:
         cfg = self.config
-        n, p = np.asarray(problem.X).shape
+        n, p = np.shape(problem.X)
         # padding is the lasso fleet substrate's contract; other
         # penalties / weighted problems serve at their exact shape
         pad_ok = _is_lasso(problem) and problem.weights is None
@@ -488,7 +498,7 @@ class Server:
             self._lru[key] = sess
             return sess
         n_b, p_b = key[-2], key[-1]
-        n, p = np.asarray(problem.X).shape
+        n, p = np.shape(problem.X)
         pad_to = (n_b, p_b) if (n_b, p_b) != (n, p) else None
         opts = self._opts
         if self.config.warm_cache is not None \
@@ -641,23 +651,32 @@ def _unit_view(value, i: int):
     return jax.tree_util.tree_map(lambda a: a[i], value)
 
 
-def _enable_persistent_cache(cache_dir: str) -> None:
+def enable_compile_cache(cache_dir: Optional[str] = None) -> str:
     """Wire JAX's persistent compilation cache with the thresholds
     zeroed, so even the small SAIF engines persist — a restarted server
-    on the same directory replays every compile from disk."""
+    on the same directory replays every compile from disk.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX already reads it
+    and no directory is set in code. Otherwise the cache goes to
+    ``cache_dir``, or to :data:`CHECKOUT_CACHE_DIR`. Returns the
+    directory in use."""
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        cache_dir = env
+    else:
+        cache_dir = str(cache_dir or CHECKOUT_CACHE_DIR)
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax latches the cache off at the first compile it sees with no
     # cache dir configured (_cache_initialized=True, _cache=None) — a
     # server opened mid-process would silently never persist. Reset so
     # the next compile re-initializes against the directory above.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
+    return cache_dir
 
 
 def open_server(config: Optional[ServerConfig] = None, *, guard=None,

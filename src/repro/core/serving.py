@@ -29,7 +29,10 @@ discipline to every failure mode between the request and the result:
   recorded in ``verdict.rungs``; no silent failures, ever.
 * **Fault containment** — transient backend ``RuntimeError``s are
   retried with jittered exponential backoff under a per-request deadline
-  (:func:`repro.runtime.fault.retry_step`); per-compile-bucket
+  (:func:`repro.runtime.fault.retry_step`); a lowering, compile or
+  out-of-memory error is deterministic and surfaces at once as a
+  :class:`BackendFault` with the compiler's message
+  (:func:`repro.runtime.fault.is_deterministic_fault`); per-compile-bucket
   :class:`~repro.runtime.fault.StragglerMonitor`s flag slow steps; a
   circuit breaker durably degrades a faulting backend (pallas -> jnp)
   for the rest of the session's lifetime.
@@ -89,12 +92,31 @@ class NumericalError(ServingError, ArithmeticError):
 
 class BackendFault(ServingError, RuntimeError):
     """A compiled backend faulted persistently — retries exhausted and,
-    where possible, the circuit breaker's degraded backend also failed."""
+    where possible, the circuit breaker's degraded backend also failed —
+    or deterministically: a lowering, compile or out-of-memory error,
+    raised at once with the compiler's message (never retried, never
+    degraded to another backend)."""
 
 
 class DeadlineExceeded(ServingError, TimeoutError):
     """The per-request wall-clock budget ran out (during retries or
     between degradation rungs)."""
+
+
+def highest_matmul_precision(fn):
+    """Run ``fn`` with every float32 matmul it traces at full precision.
+
+    The certificates (screening bounds, duality gaps, KKT residuals) are
+    float32-exact claims; a TPU's default float32 matmul rounds its inputs
+    to bfloat16 in one pass, which breaks them. The precision is part of
+    the trace context, so compiled engines carry it; on CPU it is a no-op.
+    """
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        import jax
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
 
 
 class _NonRetriable(Exception):
@@ -154,8 +176,8 @@ def validate_problem(problem) -> None:
     if X.shape[0] < 1 or X.shape[1] < 1:
         raise RequestError(f"Problem.X must be non-empty, got {X.shape}")
     _require_finite("Problem.X", X)
-    norms = np.linalg.norm(X.astype(np.float64, copy=False), axis=0)
-    dead = np.flatnonzero(norms == 0.0)
+    # an exact zero test (no float64 copy of a deployment-size design)
+    dead = np.flatnonzero(~np.any(X != 0, axis=0))
     if dead.size:
         raise RequestError(
             f"Problem.X has {dead.size} zero-norm (degenerate) column"
@@ -445,6 +467,17 @@ def _kkt_fleet_fn(loss_name: str):
                             in_axes=(None, 0, 0, 0, None)))
 
 
+def _beside(beta, X):
+    """``beta`` on X's device when X is committed to one device (a
+    feature-sharded solve returns a sharded beta; the certificate runs
+    where the user's design lives)."""
+    devices = getattr(X, "devices", None)
+    if devices is None or len(devices()) != 1:
+        return beta
+    import jax
+    return jax.device_put(beta, next(iter(devices())))
+
+
 def _wmax(a: float, b: float) -> float:
     """NaN-propagating max: a non-finite entry must dominate the
     verdict's worst-case fields, never be masked by a healthy one."""
@@ -525,6 +558,7 @@ class ServingSession:
     # the one entry point
     # ------------------------------------------------------------------
 
+    @highest_matmul_precision
     def solve(self, request, *, deadline_s: Optional[float] = None
               ) -> ServingResult:
         """Serve one request under the full runtime: admission already
@@ -634,7 +668,8 @@ class ServingSession:
 
     def _primary(self, request, t0, deadline, on_retry, events):
         from repro.runtime.fault import (RetryDeadlineExceeded, StepFailed,
-                                         StragglerMonitor, retry_step)
+                                         StragglerMonitor,
+                                         is_deterministic_fault, retry_step)
         ser = self.serving
         bucket = self._bucket(request)
         mon = self._monitors.get(bucket)
@@ -646,8 +681,18 @@ class ServingSession:
             tA = time.monotonic()
             try:
                 out = self.session.solve(request)
-            except (NotImplementedError, ServingError) as e:
+            except ServingError as e:
                 raise _NonRetriable(e) from e
+            except Exception as e:
+                if is_deterministic_fault(e):
+                    fault = BackendFault(
+                        f"deterministic backend fault (not retried): "
+                        f"{type(e).__name__}: {e}")
+                    fault.__cause__ = e
+                    raise _NonRetriable(fault) from e
+                if isinstance(e, NotImplementedError):
+                    raise _NonRetriable(e) from e
+                raise
             if mon.record(time.monotonic() - tA):
                 self._stragglers += 1
                 events.append("straggler")
@@ -793,7 +838,7 @@ class ServingSession:
                     r = u["kkt_r"]
                 else:
                     r = float(_kkt_fn(sess.config.loss)(
-                        X, u["y"], u["beta"],
+                        X, u["y"], _beside(u["beta"], X),
                         jnp.asarray(lam, X.dtype), u["pen"],
                         u["sample_w"]))
                 kkt_w = _wmax(kkt_w, r)

@@ -1,47 +1,49 @@
-"""Pallas TPU kernels: VMEM-resident cyclic coordinate-minimization bursts.
+"""Pallas TPU kernel: VMEM-resident cyclic coordinate-minimization bursts.
 
 The SAIF inner loop runs K cyclic soft-threshold sweeps over the active block
 A (n x k). k is small (<= ~1k) so the whole block, the model vector, and the
 coefficients fit in VMEM; after the initial HBM->VMEM load, an epoch performs
 ZERO HBM traffic — the TPU-native answer to the paper's tight C inner loop.
 
-Two entry points:
-
-``cm_epochs_pallas`` — the original least-squares epoch kernel (residual
-r = y - A beta maintained incrementally), kept as the simple oracle-tested
-form:
-    g      = a_j^T r
-    b_new  = S(b_j + g / ||a_j||^2,  lam / ||a_j||^2)
-    r     += (b_j - b_new) a_j
-
-``cm_burst_pallas`` — the production inner-solver backend
-(``repro.core.inner_backend``, DESIGN.md §6). Generalizations over the epoch
-kernel:
+``cm_burst_batch_pallas`` is the production inner-solver kernel
+(``repro.core.inner_backend``, DESIGN.md §6), gridded over a problem axis:
+one grid step owns one problem's whole "CM burst + dual + gap". The serial
+burst ``cm_burst_pallas`` is the fleet kernel at B = 1 (so a fleet member
+and its serial solve execute the same kernel body), and ``cm_epochs_pallas``
+is its least-squares, every-slot form. Per problem:
   * **general alpha-smooth losses** via the prox-Newton-majorized step
     (exactly ``core/cm.py::_coordinate_step``): the model vector z = A beta
     is VMEM-resident and updated rank-1; the per-step gradient f'(z) is an
     elementwise VPU pass;
   * **compact sweeps**: only the ``count`` live slots listed first in
-    ``order`` are visited, and both ``count`` and the epoch count ``n_epochs``
-    are *traced* scalars (read from VMEM inside the kernel) so one compiled
-    kernel serves every outer step of the solver — ADD-phase and polish
-    bursts alike;
+    ``order`` are visited, and both ``count`` and the epoch count
+    ``n_epochs`` are *traced* scalars (read from SMEM inside the kernel) so
+    one compiled kernel serves every outer step of the solver — ADD-phase
+    and polish bursts alike;
   * **fused dual point + duality gap**: after the burst the kernel computes
     the feasible dual point (Lemma 2 scaling, with the LS-specific tau*
     projection) and the sub-problem duality gap from the VMEM-resident
     state, so one kernel call covers the whole "CM burst + gap" of a SAIF
     outer step — no second HBM pass over the active block;
-  * **dtype-generic**: computes in A.dtype (f32 on TPU; f64 under the
-    interpreter, where the x64 test suite needs full-precision gaps), and
-    ``interpret=None`` auto-detects the backend exactly like the screening
-    kernels.
+  * **an optional unpenalized slot** (``pen`` = 0, fused LASSO's ``b``,
+    DESIGN.md §7): Newton-polished before the dual point, which is then
+    projected onto its Thm-7 equality constraint.
+
+Mosaic layout: the block travels TRANSPOSED, (k, n), so slot j's column is
+the sublane row ``a_ref[pl.ds(j, 1), :]`` — a dynamic row load Mosaic
+lowers, where a traced column slice of a value is not. Vectors are (1, n)
+or (1, k) rows; a slot's coefficient, norm, mask and weight are read and
+written with a lane select (``_pick``) so no dynamic lane offset is ever
+formed; the sweep order and the per-problem scalars sit in SMEM.
 
 The cyclic j-loop is inherently sequential (that's what "cyclic CM" means and
 what Lemma 1's rate analyzes); the n-dimension vectorizes across the 8x128
-VPU lanes. Grid = (1,): a single kernel instance owns the whole burst.
-``cm_vmem_ok`` is the block "autotuner" for this kernel family: with no free
-tiling axis the only decision is whether the burst fits the VMEM budget at
-all — the inner-backend resolver uses it to gate the pallas backend.
+VPU lanes. ``cm_vmem_ok`` is the block "autotuner" for this kernel family:
+with no free tiling axis the only decision is whether the burst fits the
+VMEM budget at all — the inner-backend resolver uses it to gate the pallas
+backend. Computation runs in A.dtype: f32 compiled (Mosaic takes no f64),
+f64 under the interpreter, where the x64 test suite needs full-precision
+gaps.
 """
 from __future__ import annotations
 
@@ -50,10 +52,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.screen.screen import default_interpret, refuse_x64
 
 # VMEM budget for the (n, k) active block: leave ~4 MB of the ~16 MB for the
 # (n,)-shaped vectors (y, z, theta), the (k,)-shaped state and headroom.
 CM_VMEM_BUDGET_BYTES = 12 * 2**20
+# scoped-VMEM limit the kernel compiles under: the pipeline double-buffers
+# the block (2 x budget) plus room for the vector working set
+CM_VMEM_LIMIT_BYTES = 2 * CM_VMEM_BUDGET_BYTES + 8 * 2**20
 
 
 def cm_vmem_ok(n: int, k: int, itemsize: int = 4, batch: int = 1) -> bool:
@@ -70,147 +78,95 @@ def cm_vmem_ok(n: int, k: int, itemsize: int = 4, batch: int = 1) -> bool:
     return per_problem * (2 if batch > 1 else 1) <= CM_VMEM_BUDGET_BYTES
 
 
-def _cm_kernel(a_ref, y_ref, beta_in_ref, colsq_ref, mask_ref, lam_ref,
-               beta_ref, r_ref, *, n_epochs: int, k: int):
-    # beta_ref is the output aliased onto beta_in_ref (input_output_aliases),
-    # so it already holds the inbound coefficients.
-    del beta_in_ref
-    # residual r = y - A beta  (beta_ref holds the inbound coefficients;
-    # we compute r once from scratch, then maintain it incrementally).
-    a = a_ref[...]                       # (n, k) — VMEM resident
-    beta0 = beta_ref[...]                # (k,)
-    r_ref[...] = y_ref[...] - jnp.dot(a, beta0,
-                                      preferred_element_type=jnp.float32)
-    lam = lam_ref[0]
-
-    def coord_step(j, _):
-        aj = a[:, j]                     # static-unroll-free dynamic column
-        csq = jnp.maximum(colsq_ref[j], 1e-30)
-        g = jnp.dot(aj, r_ref[...], preferred_element_type=jnp.float32)
-        bj = beta_ref[j]
-        u = bj + g / csq
-        t = lam / csq
-        b_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t, 0.0)
-        b_new = jnp.where(mask_ref[j], b_new, 0.0)
-        r_ref[...] += (bj - b_new) * aj
-        beta_ref[j] = b_new
-        return 0
-
-    def epoch(_, carry):
-        return jax.lax.fori_loop(0, k, coord_step, carry)
-
-    jax.lax.fori_loop(0, n_epochs, epoch, 0)
+def _dot(u, a, contract=0):
+    """(1, x) row times the (k, n) block, contracting ``a``'s dim
+    ``contract`` — at full precision: the burst's dual point and gap are
+    certificates."""
+    return jax.lax.dot_general(u, a, (((1,), (contract,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=u.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("n_epochs", "interpret"))
-def cm_epochs_pallas(A, y, beta, col_sq, mask, lam, *,
-                     n_epochs: int = 1, interpret: bool = True):
-    """K cyclic CM sweeps on the active block. Returns (beta, residual).
-
-    A: (n, k) f32 — must fit VMEM (checked: n*k*4 <= 12 MB).
-    """
-    n, k = A.shape
-    assert n * k * 4 <= CM_VMEM_BUDGET_BYTES, (
-        f"active block {n}x{k} exceeds the VMEM budget; shrink k_max or "
-        f"shard the sample dimension (see DESIGN.md §5)")
-    kernel = functools.partial(_cm_kernel, n_epochs=n_epochs, k=k)
-    beta_out, r_out = pl.pallas_call(
-        kernel,
-        in_specs=[
-            pl.BlockSpec(A.shape, lambda: (0, 0)),   # A
-            pl.BlockSpec((n,), lambda: (0,)),         # y
-            pl.BlockSpec((k,), lambda: (0,)),         # beta (aliased)
-            pl.BlockSpec((k,), lambda: (0,)),         # col_sq
-            pl.BlockSpec((k,), lambda: (0,)),         # mask
-            pl.BlockSpec((1,), lambda: (0,)),         # lam
-        ],
-        out_specs=[
-            pl.BlockSpec((k,), lambda: (0,)),
-            pl.BlockSpec((n,), lambda: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
-        input_output_aliases={2: 0},   # beta is updated in place
-        interpret=interpret,
-    )(A.astype(jnp.float32), y.astype(jnp.float32),
-      beta.astype(jnp.float32), col_sq.astype(jnp.float32),
-      mask, jnp.asarray(lam, jnp.float32).reshape(1))
-    return beta_out, r_out
+def _pick(row, slots, j):
+    """row[0, j] of a (1, k) row by lane select + sum (exact: one term)."""
+    return jnp.sum(jnp.where(slots == j, row, jnp.zeros_like(row)))
 
 
-# --------------------------------------------------------------------------
-# fused burst kernel: compact prox-Newton epochs + dual point + duality gap
-# --------------------------------------------------------------------------
-
-def _cm_burst_kernel(a_ref, y_ref, beta_in_ref, colsq_ref, mask_ref,
-                     order_ref, pen_ref, lam_ref, nep_ref, cnt_ref,
+def _cm_burst_kernel(order_ref, lam_ref, nep_ref, cnt_ref,
+                     a_ref, y_ref, beta_in_ref, colsq_ref, mask_ref, pen_ref,
                      beta_ref, z_ref, theta_ref, gap_ref, *, loss,
                      has_unpen: bool):
     from repro.core.duality import polish_unpen
-    del beta_in_ref                     # aliased onto beta_ref
-    a = a_ref[...]                      # (n, k) — VMEM resident, dead cols 0
-    y = y_ref[...]
-    lam = lam_ref[0]
+    bb = pl.program_id(0)               # problem
+    # beta_ref shares its HBM buffer with beta_in_ref, but its VMEM block
+    # starts uninitialised on the chip (only the interpreter seeds an
+    # aliased output from its input): load the inbound coefficients
+    beta_ref[...] = beta_in_ref[...]
+    k = a_ref.shape[0]                  # a_ref: (k, n), dead slots zero
+    y = y_ref[...]                      # (1, n)
+    dt = y.dtype
+    lam = lam_ref[bb]
     alpha = loss.smoothness             # static per-loss constant
-    dt = a.dtype
-    z_ref[...] = jnp.dot(a, beta_ref[...], preferred_element_type=dt)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    colsq = colsq_ref[...]              # (1, k)
+    live = mask_ref[...] > 0.5
+    pen = pen_ref[...]
+    z_ref[...] = _dot(beta_ref[...], a_ref[...])
 
     def coord_step(jj, _):
-        j = order_ref[jj]               # compact sweep: live slots only
-        aj = a[:, j]
-        lj = jnp.maximum(alpha * colsq_ref[j], 1e-30)
-        g = jnp.dot(aj, loss.grad(z_ref[...], y),
-                    preferred_element_type=dt)
-        bj = beta_ref[j]
+        j = order_ref[bb * k + jj]      # compact sweep: live slots only
+        aj = a_ref[pl.ds(j, 1), :]      # (1, n): slot j's design column
+        lj = jnp.maximum(alpha * _pick(colsq, slots, j), 1e-30)
+        g = jnp.sum(aj * loss.grad(z_ref[...], y))
+        beta = beta_ref[...]
+        bj = _pick(beta, slots, j)
         u = bj - g / lj
-        t = lam * pen_ref[j] / lj       # pen=0: exact unpenalized step
+        t = lam * _pick(pen, slots, j) / lj     # pen=0: exact unpenalized
         b_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t, 0.0)
-        b_new = jnp.where(mask_ref[j], b_new, 0.0)
+        b_new = jnp.where(_pick(live.astype(dt), slots, j) > 0.5, b_new, 0.0)
         z_ref[...] += (b_new - bj) * aj
-        beta_ref[j] = b_new
+        beta_ref[...] = jnp.where(slots == j, b_new, beta)
         return 0
 
     def epoch(_, carry):
-        return jax.lax.fori_loop(0, cnt_ref[0], coord_step, carry)
+        return jax.lax.fori_loop(0, cnt_ref[bb], coord_step, carry)
 
-    jax.lax.fori_loop(0, nep_ref[0], epoch, 0)
+    jax.lax.fori_loop(0, nep_ref[bb], epoch, 0)
 
     # ---- fused dual-point / duality-gap tail (still VMEM-resident) -------
+    a = a_ref[...]
     beta = beta_ref[...]
-    pen = pen_ref[...]
-    z = jnp.dot(a, beta, preferred_element_type=dt)   # fresh, drift-free
+    z = _dot(beta, a)                                  # fresh, drift-free
     if has_unpen:
         # b's column — the one live slot with pen = 0 — shared by the
         # Newton polish and the equality projection below
-        w = jnp.where(mask_ref[...], 1.0 - pen, 0.0).astype(dt)
-        ab = jnp.dot(a, w, preferred_element_type=dt)   # (n,)
+        w = jnp.where(live, 1.0 - pen, 0.0).astype(dt)
+        ab = _dot(w, a)                                 # (1, n)
         if loss.name != "least_squares":
             # General loss: Newton-polish the unpenalized coordinate to
             # stationarity before forming the dual point, so x_b^T f'(z)
             # ~ 0 and the equality projection is a benign ~0 correction
             # (duality.polish_unpen — the same pure-jax fold runs inside
             # the kernel, DESIGN.md §7).
-            b_cur = jnp.dot(beta, w, preferred_element_type=dt)
+            b_cur = jnp.sum(beta * w)
             b_new, z = polish_unpen(loss, ab, y, z, b_cur)
             beta = jnp.where(w > 0.5, b_new, beta)
             beta_ref[...] = beta
     z_ref[...] = z
-    hat = -loss.grad(z, y) / lam                      # unscaled dual point
+    hat = -loss.grad(z, y) / lam                       # unscaled dual point
     if has_unpen:
         # Thm-7 equality constraint x_b^T theta = 0: project hat onto the
         # hyperplane before scaling (duality.feasible_dual, DESIGN.md §7)
-        sq_b = jnp.dot(ab, ab, preferred_element_type=dt)
-        hat = hat - ab * (jnp.dot(ab, hat, preferred_element_type=dt)
-                          / jnp.maximum(sq_b, 1e-30))
-    corr = jnp.dot(hat, a, preferred_element_type=dt)  # (k,); dead cols -> 0
+        sq_b = jnp.sum(ab * ab)
+        hat = hat - ab * (jnp.sum(ab * hat) / jnp.maximum(sq_b, 1e-30))
+    # (1, k) slot correlations hat^T A; dead slots -> 0
+    corr = _dot(hat, a, contract=1)
     max_corr = jnp.max(jnp.abs(corr) * pen)            # penalized cols only
     if loss.name == "least_squares":
         # DPP-style optimal scaling (duality.feasible_dual, LS branch)
         bound = 1.0 / jnp.maximum(max_corr, 1e-30)
         sq = jnp.sum(hat * hat)
-        tau_star = jnp.dot(y, hat) / (lam * jnp.maximum(sq, 1e-30))
+        tau_star = jnp.sum(y * hat) / (lam * jnp.maximum(sq, 1e-30))
         tau = jnp.clip(tau_star, -bound, bound)
         tau = jnp.where(jnp.isfinite(tau), tau,
                         1.0 / jnp.maximum(max_corr, 1.0))
@@ -221,89 +177,37 @@ def _cm_burst_kernel(a_ref, y_ref, beta_in_ref, colsq_ref, mask_ref,
     theta_ref[...] = theta
     p_val = jnp.sum(loss.value(z, y)) + lam * jnp.sum(pen * jnp.abs(beta))
     d_val = -jnp.sum(loss.conj(-lam * theta, y))
-    gap_ref[0] = p_val - d_val
-
-
-# --------------------------------------------------------------------------
-# problem-gridded fleet burst kernel (batch engine, DESIGN.md §8)
-# --------------------------------------------------------------------------
-
-def _cm_burst_batch_kernel(a_ref, y_ref, beta_in_ref, colsq_ref, mask_ref,
-                           order_ref, lam_ref, nep_ref, cnt_ref,
-                           beta_ref, z_ref, theta_ref, gap_ref, *, loss):
-    """One grid step = one problem's whole "CM burst + dual + gap".
-
-    The body is :func:`_cm_burst_kernel` without the unpenalized-slot
-    machinery (fleets are plain LASSO, §8), reading this problem's blocks
-    (leading length-1 problem dim). Per-problem traced epoch/live counts
-    arrive through the (1,)-blocked ``nep``/``cnt`` operands, so a finished
-    problem's grid step runs a zero-trip burst — only the initial z matmul
-    and the dual/gap tail touch the VPU for it.
-    """
-    del beta_in_ref                     # aliased onto beta_ref
-    a = a_ref[0]                        # (n, k) this problem's active block
-    y = y_ref[0, :]
-    lam = lam_ref[0]
-    alpha = loss.smoothness
-    dt = a.dtype
-    z_ref[0, :] = jnp.dot(a, beta_ref[0, :], preferred_element_type=dt)
-
-    def coord_step(jj, _):
-        j = order_ref[0, jj]
-        aj = a[:, j]
-        lj = jnp.maximum(alpha * colsq_ref[0, j], 1e-30)
-        g = jnp.dot(aj, loss.grad(z_ref[0, :], y),
-                    preferred_element_type=dt)
-        bj = beta_ref[0, j]
-        u = bj - g / lj
-        t = lam / lj
-        b_new = jnp.sign(u) * jnp.maximum(jnp.abs(u) - t, 0.0)
-        b_new = jnp.where(mask_ref[0, j], b_new, 0.0)
-        z_ref[0, :] += (b_new - bj) * aj
-        beta_ref[0, j] = b_new
-        return 0
-
-    def epoch(_, carry):
-        return jax.lax.fori_loop(0, cnt_ref[0], coord_step, carry)
-
-    jax.lax.fori_loop(0, nep_ref[0], epoch, 0)
-
-    # ---- fused dual-point / duality-gap tail (VMEM-resident) -------------
-    beta = beta_ref[0, :]
-    z = jnp.dot(a, beta, preferred_element_type=dt)
-    z_ref[0, :] = z
-    hat = -loss.grad(z, y) / lam
-    corr = jnp.dot(hat, a, preferred_element_type=dt)
-    max_corr = jnp.max(jnp.abs(corr))
-    if loss.name == "least_squares":
-        bound = 1.0 / jnp.maximum(max_corr, 1e-30)
-        sq = jnp.sum(hat * hat)
-        tau_star = jnp.dot(y, hat) / (lam * jnp.maximum(sq, 1e-30))
-        tau = jnp.clip(tau_star, -bound, bound)
-        tau = jnp.where(jnp.isfinite(tau), tau,
-                        1.0 / jnp.maximum(max_corr, 1.0))
-        theta = tau * hat
-    else:
-        theta = hat / jnp.maximum(max_corr, 1.0)
-        theta = -loss.dual_clip(-lam * theta, y) / lam
-    theta_ref[0, :] = theta
-    p_val = jnp.sum(loss.value(z, y)) + lam * jnp.sum(jnp.abs(beta))
-    d_val = -jnp.sum(loss.conj(-lam * theta, y))
-    gap_ref[0] = p_val - d_val
+    gap_ref[...] = jnp.full((1, 1), p_val - d_val, dt)
 
 
 @functools.partial(jax.jit, static_argnames=("loss_name", "interpret"))
 def cm_burst_batch_pallas(A, Y, beta, col_sq, mask, order, lam, n_epochs,
-                          count, *, loss_name: str = "least_squares",
+                          count, pen=None, *,
+                          loss_name: str = "least_squares",
                           interpret: bool | None = None):
     """Fleet "CM burst + gap": grid axis over problems, one launch for B.
 
-    Args mirror :func:`cm_burst_pallas` with a leading problem axis:
-    A (B, n, k) per-problem active blocks, Y (B, n), beta/col_sq/mask/order
-    (B, k), lam/n_epochs/count (B,). Each grid step owns one problem's
-    burst end-to-end in VMEM; the double-buffered fleet budget is checked
-    by ``cm_vmem_ok(..., batch=B)``.
-    Returns (beta (B, k), z (B, n), theta (B, n), gap (B,)).
+    Args:
+      A:        (B, n, k) per-problem active blocks, dead columns zeroed.
+                Computation runs in A.dtype.
+      Y:        (B, n) responses.
+      beta:     (B, k) inbound coefficients (0 on dead slots).
+      col_sq:   (B, k) squared column norms; mask (B, k) live slots.
+      order:    (B, k) int32 slot permutations, the ``count`` live slots
+                first.
+      lam, n_epochs, count: (B,) — the epoch and live-slot counts are
+                traced (the solver batches ADD vs polish bursts through
+                this one compiled kernel; a finished problem runs a
+                zero-trip burst).
+      pen:      (B, k) optional per-slot l1 weight: 0 marks the
+                always-resident unpenalized slot (fused LASSO's ``b``,
+                DESIGN.md §7), which also switches the dual tail to the
+                Thm-7 equality-projected scaling. None = all penalized.
+    Returns (beta (B, k), z (B, n), theta (B, n), gap (B,)): the updated
+    coefficients, the fresh model vector z = A beta, the feasible dual
+    point, and the sub-problem duality gap — everything a SAIF outer step
+    needs from the inner solver. The double-buffered fleet budget is
+    checked by ``cm_vmem_ok(..., batch=B)``.
     """
     from repro.core.losses import get_loss
 
@@ -315,109 +219,72 @@ def cm_burst_batch_pallas(A, Y, beta, col_sq, mask, order, lam, n_epochs,
         f"double-buffered VMEM budget; shrink k_max or shard the sample "
         f"dimension (see DESIGN.md §5/§8)")
     if interpret is None:
-        from repro.kernels.screen.screen import default_interpret
         interpret = default_interpret()
-    kernel = functools.partial(_cm_burst_batch_kernel, loss=loss)
-    blk = pl.BlockSpec((1, n, k), lambda bb: (bb, 0, 0))
-    vec_k = pl.BlockSpec((1, k), lambda bb: (bb, 0))
-    vec_n = pl.BlockSpec((1, n), lambda bb: (bb, 0))
-    one = pl.BlockSpec((1,), lambda bb: (bb,))
+    refuse_x64(interpret, dt)
+    has_unpen = pen is not None
+    if pen is None:
+        pen = jnp.ones((b, k), dt)
+    kernel = functools.partial(_cm_burst_kernel, loss=loss,
+                               has_unpen=has_unpen)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    blk = pl.BlockSpec((None, k, n), lambda i: (i, 0, 0))
+    row_k = pl.BlockSpec((None, 1, k), lambda i: (i, 0, 0))
+    row_n = pl.BlockSpec((None, 1, n), lambda i: (i, 0, 0))
+
+    def rows(v):
+        return jnp.asarray(v).astype(dt)[:, None, :]
+
     beta_out, z_out, theta_out, gap_out = pl.pallas_call(
         kernel,
         grid=(b,),
-        in_specs=[
-            blk,                                      # A
-            vec_n,                                    # Y
-            vec_k,                                    # beta (aliased)
-            vec_k,                                    # col_sq
-            vec_k,                                    # mask
-            vec_k,                                    # order
-            one,                                      # lam
-            one,                                      # n_epochs
-            one,                                      # count
-        ],
-        out_specs=[vec_k, vec_n, vec_n, one],
+        in_specs=[smem, smem, smem, smem,             # order/lam/nep/count
+                  blk,                                # A^T
+                  row_n,                              # Y
+                  row_k,                              # beta (aliased)
+                  row_k, row_k, row_k],               # col_sq, mask, pen
+        out_specs=[row_k, row_n, row_n,
+                   pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), dt),         # beta
-            jax.ShapeDtypeStruct((b, n), dt),         # z
-            jax.ShapeDtypeStruct((b, n), dt),         # theta
-            jax.ShapeDtypeStruct((b,), dt),           # gap
+            jax.ShapeDtypeStruct((b, 1, k), dt),      # beta
+            jax.ShapeDtypeStruct((b, 1, n), dt),      # z
+            jax.ShapeDtypeStruct((b, 1, n), dt),      # theta
+            jax.ShapeDtypeStruct((b, 1, 1), dt),      # gap
         ],
-        input_output_aliases={2: 0},                  # beta updated in place
+        input_output_aliases={6: 0},                  # beta updated in place
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=CM_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(A, Y.astype(dt), beta.astype(dt), col_sq.astype(dt), mask,
-      order.astype(jnp.int32), jnp.asarray(lam, dt),
-      jnp.asarray(n_epochs, jnp.int32), jnp.asarray(count, jnp.int32))
-    return beta_out, z_out, theta_out, gap_out
+    )(jnp.asarray(order, jnp.int32).reshape(b * k),
+      jnp.asarray(lam, dt).reshape(b),
+      jnp.asarray(n_epochs, jnp.int32).reshape(b),
+      jnp.asarray(count, jnp.int32).reshape(b),
+      jnp.swapaxes(A, 1, 2), rows(Y), rows(beta), rows(col_sq), rows(mask),
+      rows(pen))
+    return beta_out[:, 0], z_out[:, 0], theta_out[:, 0], gap_out[:, 0, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("loss_name", "interpret"))
 def cm_burst_pallas(A, y, beta, col_sq, mask, order, lam, n_epochs, count,
                     pen=None, *, loss_name: str = "least_squares",
                     interpret: bool | None = None):
-    """One fused "CM burst + gap" call on the active block.
+    """One problem's fused "CM burst + gap": the fleet kernel at B = 1.
+    Args as :func:`cm_burst_batch_pallas` without the problem axis (A is
+    (n, k)); returns (beta (k,), z (n,), theta (n,), gap scalar)."""
+    out = cm_burst_batch_pallas(
+        A[None], jnp.asarray(y)[None], jnp.asarray(beta)[None],
+        jnp.asarray(col_sq)[None], jnp.asarray(mask)[None],
+        jnp.asarray(order)[None], jnp.reshape(lam, (1,)),
+        jnp.reshape(n_epochs, (1,)), jnp.reshape(count, (1,)),
+        None if pen is None else jnp.asarray(pen)[None],
+        loss_name=loss_name, interpret=interpret)
+    return tuple(o[0] for o in out)
 
-    Args:
-      A:        (n, k) active design block, dead columns zeroed. Computation
-                runs in A.dtype (f32 on TPU; f64 under the interpreter).
-      beta:     (k,) inbound coefficients (0 on dead slots).
-      order:    (k,) int32 slot permutation, the ``count`` live slots first.
-      n_epochs: traced sweep count (the solver batches ADD vs polish bursts
-                through this one compiled kernel).
-      count:    traced live-slot count.
-      pen:      (k,) optional per-slot l1 weight: 0 marks the always-resident
-                unpenalized slot (fused LASSO's ``b``, DESIGN.md §7), which
-                also switches the dual tail to the Thm-7 equality-projected
-                scaling. None = all penalized (the plain-LASSO fast path).
-    Returns (beta, z, theta, gap): the updated coefficients, the fresh model
-    vector z = A beta, the feasible dual point, and the sub-problem duality
-    gap — everything a SAIF outer step needs from the inner solver.
-    """
-    from repro.core.losses import get_loss
 
-    loss = get_loss(loss_name)
-    n, k = A.shape
-    dt = A.dtype
-    assert cm_vmem_ok(n, k, dt.itemsize), (
-        f"active block {n}x{k} ({dt}) exceeds the VMEM budget; shrink "
-        f"k_max or shard the sample dimension (see DESIGN.md §5/§6)")
-    if interpret is None:
-        from repro.kernels.screen.screen import default_interpret
-        interpret = default_interpret()
-    has_unpen = pen is not None
-    if pen is None:
-        pen = jnp.ones((k,), dt)
-    kernel = functools.partial(_cm_burst_kernel, loss=loss,
-                               has_unpen=has_unpen)
-    vec_k = pl.BlockSpec((k,), lambda: (0,))
-    vec_n = pl.BlockSpec((n,), lambda: (0,))
-    one = pl.BlockSpec((1,), lambda: (0,))
-    beta_out, z_out, theta_out, gap_out = pl.pallas_call(
-        kernel,
-        in_specs=[
-            pl.BlockSpec(A.shape, lambda: (0, 0)),    # A
-            vec_n,                                    # y
-            vec_k,                                    # beta (aliased)
-            vec_k,                                    # col_sq
-            vec_k,                                    # mask
-            vec_k,                                    # order
-            vec_k,                                    # pen
-            one,                                      # lam
-            one,                                      # n_epochs
-            one,                                      # count
-        ],
-        out_specs=[vec_k, vec_n, vec_n, one],
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), dt),           # beta
-            jax.ShapeDtypeStruct((n,), dt),           # z
-            jax.ShapeDtypeStruct((n,), dt),           # theta
-            jax.ShapeDtypeStruct((1,), dt),           # gap
-        ],
-        input_output_aliases={2: 0},                  # beta updated in place
-        interpret=interpret,
-    )(A, y.astype(dt), beta.astype(dt), col_sq.astype(dt), mask,
-      order.astype(jnp.int32), pen.astype(dt),
-      jnp.asarray(lam, dt).reshape(1),
-      jnp.asarray(n_epochs, jnp.int32).reshape(1),
-      jnp.asarray(count, jnp.int32).reshape(1))
-    return beta_out, z_out, theta_out, gap_out[0]
+def cm_epochs_pallas(A, y, beta, col_sq, mask, lam, *, n_epochs: int = 1,
+                     interpret: bool | None = None):
+    """K least-squares cyclic sweeps over every slot of A (n, k) in order.
+    Returns (beta, residual y - A beta)."""
+    k = A.shape[1]
+    beta_out, z, _, _ = cm_burst_pallas(
+        A, y, beta, col_sq, mask, jnp.arange(k, dtype=jnp.int32), lam,
+        n_epochs, k, interpret=interpret)
+    return beta_out, jnp.asarray(y, z.dtype) - z

@@ -8,16 +8,20 @@ Theorem-6 transform collapses to the *suffix sums* of the design columns:
     x_tilde_e = S[:, e+1]          (edge e's transformed column)
     x_b       = S[:, 0]            (the unpenalized b column)
 
-TPU mapping: grid = (p/BP,), tiles visited RIGHT to LEFT (the index map
-reverses the program id — TPU grids execute sequentially, so the (n,)-
-shaped running carry can live in an output block with a constant index map
-that every step revisits, the same accumulation pattern as the screening
-kernels). Inside a tile the suffix is an exact *right fold*
-(acc = x[:, l] + acc, one IEEE add per column): bitwise-identical to the
-dense numpy reference ``repro.core.fused.transform_design``, which is what
-the device-transform parity suite asserts. A triangular-matmul form would
-feed the MXU but re-associates the sums; the transform runs once per fused
-problem, so the exact fold wins (DESIGN.md §7).
+TPU mapping: the kernel works on the TRANSPOSED design X^T (p, n), so a
+design column is a sublane row and the fold reads and writes it with a
+dynamic row slice ``x_ref[pl.ds(l, 1), :]`` (Mosaic lowers that, where a
+traced column slice of a value is not). Grid = (p/BP,), tiles visited
+BOTTOM to TOP (the index map reverses the program id — TPU grids execute
+sequentially, so the (1, n)-shaped running carry can live in an output
+block with a constant index map that every step revisits, the same
+accumulation pattern as the screening kernels). Inside a tile the suffix is
+an exact *right fold* (acc = x[:, l] + acc, one IEEE add per column):
+bitwise-identical to the dense numpy reference
+``repro.core.fused.transform_design``, which is what the device-transform
+parity suite asserts. A triangular-matmul form would feed the MXU but
+re-associates the sums; the transform runs once per fused problem, so the
+exact fold wins (DESIGN.md §7), and so do the two XLA transposes around it.
 
 Execution mode: ``interpret=None`` auto-detects like every other kernel in
 ``repro.kernels`` — compiled Mosaic on TPU, interpreter fallback on CPU.
@@ -30,9 +34,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.screen.screen import default_interpret
+from repro.kernels.screen.screen import default_interpret, refuse_x64
 
-# the (n_pad, bp) tile + its output + the (n_pad,) carry, double-buffered
+# the (bp, n_pad) tile + its output + the (n_pad,) carry, double-buffered
 FUSED_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
@@ -41,9 +45,9 @@ def _round_up(x: int, m: int) -> int:
 
 
 def autotune_chain_block(n: int, p: int, *, dtype_bytes: int = 4) -> int:
-    """Lane-dim tile width bp for the suffix-sum kernel (multiple of 128),
-    shrunk until in+out tiles fit the VMEM budget at this n."""
-    n_pad = _round_up(max(n, 1), 8)
+    """Tile height bp (columns of X per tile, a multiple of 128), shrunk
+    until in+out tiles fit the VMEM budget at this n."""
+    n_pad = _round_up(max(n, 1), 128)
     bp = min(512, _round_up(max(p, 1), 128))
     while bp > 128 and 2 * n_pad * bp * dtype_bytes > FUSED_VMEM_BUDGET_BYTES:
         bp //= 2
@@ -51,25 +55,22 @@ def autotune_chain_block(n: int, p: int, *, dtype_bytes: int = 4) -> int:
 
 
 def _chain_suffix_kernel(x_ref, s_ref, tot_ref, *, bp: int):
-    i = pl.program_id(0)        # i-th tile from the RIGHT (index map flips)
+    i = pl.program_id(0)        # i-th tile from the BOTTOM (index map flips)
 
     @pl.when(i == 0)
     def _init():
         tot_ref[...] = jnp.zeros_like(tot_ref)
 
-    x = x_ref[...]              # (n_pad, bp)
-    carry = tot_ref[...]        # (n_pad,) suffix total of all tiles right
-
-    def fold(jj, state):
-        acc, out = state
+    def fold(jj, acc):
         l = bp - 1 - jj
-        acc = x[:, l] + acc     # ONE IEEE add per column: exact right fold
-        out = jax.lax.dynamic_update_index_in_dim(out, acc, l, 1)
-        return acc, out
+        acc = x_ref[pl.ds(l, 1), :] + acc   # ONE IEEE add: exact right fold
+        s_ref[pl.ds(l, 1), :] = acc
+        return acc
 
-    acc, out = jax.lax.fori_loop(0, bp, fold, (carry, jnp.zeros_like(x)))
-    s_ref[...] = out
-    tot_ref[...] = acc
+    # the carry holds the completed suffix of every tile below this one
+    # int32 bounds: the index stays int32 under jax_enable_x64 too
+    tot_ref[...] = jax.lax.fori_loop(jnp.int32(0), jnp.int32(bp), fold,
+                                     tot_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "interpret"))
@@ -77,7 +78,7 @@ def chain_suffix_sums_pallas(X, *, bp: int | None = None,
                              interpret: bool | None = None):
     """Suffix sums S[:, v] = sum_{u >= v} X[:, u] of the design columns.
 
-    Computation runs in X.dtype (f32 on TPU, f64 under the x64
+    Computation runs in X.dtype (f32 compiled, f64 under the x64
     interpreter); the fold order matches the dense numpy reference exactly
     (see the module docstring), so the parity tests compare bitwise.
     """
@@ -87,33 +88,29 @@ def chain_suffix_sums_pallas(X, *, bp: int | None = None,
         bp = autotune_chain_block(n, p, dtype_bytes=dt.itemsize)
     if interpret is None:
         interpret = default_interpret()
-    n_pad = -n % 8
-    p_pad = -p % bp
-    # rows pad with zeros (sliced off); columns pad on the RIGHT with
-    # zeros — a zero column leaves the right fold bitwise unchanged
-    Xp = jnp.pad(X, ((0, n_pad), (0, p_pad)))
-    np_, pp = Xp.shape
+    refuse_x64(interpret, dt)
+    # rows of X^T pad at the BOTTOM with zeros — a zero column leaves the
+    # right fold bitwise unchanged; lanes pad to 128 and are sliced off
+    Xt = jnp.pad(X.T, ((0, -p % bp), (0, -n % 128)))
+    pp, np_ = Xt.shape
     p_blocks = pp // bp
     kernel = functools.partial(_chain_suffix_kernel, bp=bp)
-    S, _ = pl.pallas_call(
+    # visit tiles bottom-to-top so the carry always holds the completed
+    # suffix of everything to the right (below, in X^T)
+    tile = pl.BlockSpec((bp, np_), lambda i: (p_blocks - 1 - i, 0))
+    St, _ = pl.pallas_call(
         kernel,
         grid=(p_blocks,),
-        in_specs=[
-            # visit tiles right-to-left so the carry always holds the
-            # completed suffix of everything to the right
-            pl.BlockSpec((np_, bp), lambda i: (0, p_blocks - 1 - i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((np_, bp), lambda i: (0, p_blocks - 1 - i)),
-            pl.BlockSpec((np_,), lambda i: (0,)),   # carry (revisited)
-        ],
+        in_specs=[tile],
+        out_specs=[tile,
+                   pl.BlockSpec((1, np_), lambda i: (0, 0))],  # carry
         out_shape=[
-            jax.ShapeDtypeStruct((np_, pp), dt),    # S
-            jax.ShapeDtypeStruct((np_,), dt),       # running total
+            jax.ShapeDtypeStruct((pp, np_), dt),    # S^T
+            jax.ShapeDtypeStruct((1, np_), dt),     # running total
         ],
         interpret=interpret,
-    )(Xp)
-    return S[:n, :p]
+    )(Xt)
+    return St[:p, :n].T
 
 
 def chain_suffix_sums_ref(X):

@@ -50,8 +50,6 @@ def ub_histogram(ub, lb_sorted, *, bp=None, interpret: bool | None = None):
 def cm_epochs(A, y, beta, col_sq, mask, lam, *, n_epochs=1,
               interpret: bool | None = None):
     """VMEM-resident cyclic CM sweeps (least squares)."""
-    if interpret is None:
-        interpret = not on_tpu()
     return cm_epochs_pallas(A, y, beta, col_sq, mask, lam,
                             n_epochs=n_epochs, interpret=interpret)
 

@@ -43,14 +43,6 @@ _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "f8e5m2": 1, "s16": 2, "u16": 2}
 
 
-def cost_dict(cost) -> Dict[str, float]:
-    """Normalize Compiled.cost_analysis() — dict on newer jaxlibs, a
-    one-element list of dicts on older ones (None if unavailable)."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, float]:
     """Sum output operand bytes of every collective op in the compiled HLO."""
     totals: Dict[str, float] = {}
@@ -198,7 +190,7 @@ def _lower_one(arch: str, shape_name: str, mesh, cfg, *,
         compile_s = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     return {
@@ -296,7 +288,7 @@ def lower_saif_screen(mesh, *, n: int = 4096, log2_p: int = 26,
         t0 = time.time()
         compiled = lowered.compile()
         compile_s = time.time() - t0
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     coll = collective_bytes(compiled.as_text())
     flops = float(cost.get("flops", 0.0))

@@ -7,20 +7,28 @@ the single real CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the solver's host-side prep
+    (``jnp.median`` over a feature-sharded vector, top-k merges) relies on
+    the compiler inserting the collectives, which Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(data=16, model=16) single pod; (pod=2, data=16, model=16) two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the real local devices (CPU tests / examples)."""
     n = jax.device_count()
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -31,16 +31,20 @@ def main(argv=None):
     ap.add_argument("--max-wait-ms", type=float, default=5.0)
     ap.add_argument("--max-sessions", type=int, default=8)
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent compilation cache directory")
+                    help="persistent compilation cache directory (default: "
+                         "$JAX_COMPILATION_CACHE_DIR, else .jax_cache in "
+                         "the checkout)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     from repro import Problem, Scalar, open_server
     from repro.core.saif import SaifConfig
+    from repro.core.server import CHECKOUT_CACHE_DIR
 
     server = open_server(
         max_batch=args.max_batch, max_wait_ms=args.max_wait_ms,
-        max_sessions=args.max_sessions, cache_dir=args.cache_dir,
+        max_sessions=args.max_sessions,
+        cache_dir=args.cache_dir or CHECKOUT_CACHE_DIR,
         solver=SaifConfig())
 
     rng = np.random.default_rng(args.seed)

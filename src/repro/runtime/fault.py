@@ -39,6 +39,27 @@ class RetryDeadlineExceeded(StepFailed):
     map it onto a deadline-typed serving error)."""
 
 
+# XLA status codes of failures that recur on every attempt: a program the
+# compiler refuses, an allocation that does not fit, an unsupported op
+_DETERMINISTIC_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                         "UNIMPLEMENTED", "FAILED_PRECONDITION", "INTERNAL")
+_LOWERING_ERRORS = ("MLIRError", "LoweringException", "VerificationError")
+
+
+def is_deterministic_fault(e: BaseException) -> bool:
+    """A lowering, compile or out-of-memory error: the same inputs fail
+    the same way on every attempt, so retrying it only hides it."""
+    if type(e).__name__ in _LOWERING_ERRORS:
+        return True
+    msg = str(e)
+    if "Mosaic" in msg or "Pallas TPU lowering" in msg:
+        return True
+    import jax
+    if isinstance(e, jax.errors.JaxRuntimeError):
+        return msg.split(":", 1)[0].strip() in _DETERMINISTIC_STATUS
+    return False
+
+
 def backoff_delay(attempt: int, base_s: float, mult: float, jitter: float,
                   rng: Optional[random.Random] = None) -> float:
     """Jittered exponential backoff: ``base * mult**(attempt-1)`` scaled

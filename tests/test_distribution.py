@@ -21,6 +21,8 @@ from repro.optim import adamw, compress
 from repro.runtime.fault import (PreemptionGuard, StepFailed,
                                  StragglerMonitor, retry_step)
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 class FakeMesh:
     """Shape-only stand-in so sharding rules can be tested against the
@@ -184,16 +186,16 @@ def test_train_resume_end_to_end(tmp_path):
             "--log-every", "1", "--lr", "1e-3"]
     r1 = subprocess.run(base + ["--steps", "6", "--ckpt-dir",
                                 str(tmp_path / "a"), "--ckpt-every", "3"],
-                        capture_output=True, text=True, env=env, cwd="/root/repo")
+                        capture_output=True, text=True, env=env, cwd=REPO_ROOT)
     assert r1.returncode == 0, r1.stderr[-2000:]
     r2 = subprocess.run(base + ["--steps", "12", "--ckpt-dir",
                                 str(tmp_path / "a"), "--ckpt-every", "3"],
-                        capture_output=True, text=True, env=env, cwd="/root/repo")
+                        capture_output=True, text=True, env=env, cwd=REPO_ROOT)
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "[resume] restored step 6" in r2.stdout
     r3 = subprocess.run(base + ["--steps", "12", "--ckpt-dir",
                                 str(tmp_path / "b"), "--ckpt-every", "100"],
-                        capture_output=True, text=True, env=env, cwd="/root/repo")
+                        capture_output=True, text=True, env=env, cwd=REPO_ROOT)
     losses_resumed = [l.split()[-1] for l in r2.stdout.splitlines()
                       if l.startswith("step ")]
     losses_straight = [l.split()[-1] for l in r3.stdout.splitlines()
@@ -230,7 +232,7 @@ print("DIST_OK")
 """
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, cwd="/root/repo", timeout=600)
+                       text=True, env=env, cwd=REPO_ROOT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "DIST_OK" in r.stdout
 
@@ -271,7 +273,7 @@ print("DIST_BATCH_OK")
 """
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, cwd="/root/repo", timeout=600)
+                       text=True, env=env, cwd=REPO_ROOT, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "DIST_BATCH_OK" in r.stdout
 

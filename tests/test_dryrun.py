@@ -7,6 +7,8 @@ import sys
 
 import pytest
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.mark.parametrize("multi", [False, True], ids=["1pod", "2pod"])
 def test_dryrun_whisper_prefill(tmp_path, multi):
@@ -18,7 +20,7 @@ def test_dryrun_whisper_prefill(tmp_path, multi):
         cmd.append("--multi-pod")
     env = dict(os.environ, PYTHONPATH="src")
     r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                       cwd="/root/repo", timeout=1200)
+                       cwd=REPO_ROOT, timeout=1200)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     recs = [json.loads(l) for l in out.read_text().splitlines()]
     assert len(recs) == 1 and recs[0]["status"] == "ok"
@@ -38,7 +40,7 @@ def test_saif_screen_row(tmp_path):
     r = subprocess.run([sys.executable, "-m", "repro.launch.dryrun",
                         "--saif-screen", "--out", str(out)],
                        capture_output=True, text=True, env=env,
-                       cwd="/root/repo", timeout=1200)
+                       cwd=REPO_ROOT, timeout=1200)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     rec = json.loads(out.read_text().splitlines()[0])
     assert rec["status"] == "ok"

@@ -223,7 +223,7 @@ def test_saif_path_inner_backends_warm_equals_cold(rng):
     for be in INNER_BACKENDS:
         cfg = SaifConfig(eps=1e-8, inner_backend=be)
         eng = saif_path(X, y, lams, cfg)
-        assert eng.n_compilations is None or eng.n_compilations <= 10
+        assert eng.n_compilations <= 10
         sups = []
         for lam, beta in zip(eng.lams, eng.betas):
             cold = saif(X, y, float(lam), cfg)
@@ -263,9 +263,11 @@ def test_screen_backend_auto_policy():
 
 
 def test_inner_backend_auto_policy():
-    # gram whenever the loss is LS and capacity is not >> n
-    assert resolve_inner_backend("auto", "least_squares", 100, 256) == "gram"
-    assert resolve_inner_backend("auto", "least_squares", 2000, 256) == "gram"
+    # on TPU the VMEM kernel whenever the block fits; elsewhere gram
+    # whenever the loss is LS and capacity is not >> n
+    ls = "pallas" if on_tpu() else "gram"
+    assert resolve_inner_backend("auto", "least_squares", 100, 256) == ls
+    assert resolve_inner_backend("auto", "least_squares", 2000, 256) == ls
     # capacity way beyond the crossover: fall back (jnp on CPU)
     big_k = int(GRAM_CROSSOVER * 10) + 10
     fallback = resolve_inner_backend("auto", "least_squares", 10, big_k)
@@ -283,6 +285,35 @@ def test_inner_backend_auto_policy():
     assert resolve_inner_backend("pallas", "logistic", 100, 64) == "pallas"
     with pytest.raises(ValueError):
         resolve_inner_backend("pallas", "least_squares", 100_000, 1024)
+
+
+def test_tpu_policy_keeps_x64_off_the_kernels(monkeypatch):
+    """On TPU the Pallas kernels take float32 with x64 off (Mosaic has no
+    f64): ``auto`` keeps float64 problems and x64 mode on the XLA paths,
+    and an explicit ``pallas`` for them raises instead of degrading."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32, f64 = jnp.float32, jnp.float64
+    with jax.enable_x64(False):
+        assert resolve_backend("auto", f32) == "pallas"
+        for loss in ("logistic", "least_squares"):
+            assert resolve_inner_backend("auto", loss, 100, 64,
+                                         f32) == "pallas"
+        # a block beyond the VMEM budget keeps least squares on gram
+        assert resolve_inner_backend("auto", "least_squares", 100_000, 64,
+                                     f32) == "gram"
+        assert resolve_backend("pallas", f32) == "pallas"
+    with jax.enable_x64(True):
+        for dt in (f32, f64):
+            assert resolve_backend("auto", dt) == "jnp"
+            assert resolve_inner_backend("auto", "logistic", 100, 64,
+                                         dt) == "jnp"
+            with pytest.raises(ValueError, match="x64"):
+                resolve_backend("pallas", dt)
+            with pytest.raises(ValueError, match="x64"):
+                resolve_inner_backend("pallas", "logistic", 100, 64, dt)
+        # the gram engine is XLA, untouched by the refusal
+        assert resolve_inner_backend("auto", "least_squares", 100, 256,
+                                     f64) == "gram"
 
 
 def test_gram_epochs_touch_no_n_sized_arrays():

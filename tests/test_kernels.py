@@ -108,3 +108,28 @@ def test_screen_dtype_sweep(rng, dtype):
                                atol=tol * scale)
     np.testing.assert_allclose(np.asarray(u, np.float32), ur,
                                atol=tol * scale)
+
+
+@pytest.mark.parametrize("kernel", ["screen", "cm", "chain"])
+def test_compiled_kernels_refuse_x64(rng, kernel):
+    """A compiled (Mosaic) kernel refuses float64 operands and x64 mode
+    with a TypeError before lowering; the interpreter takes them."""
+    from repro.kernels.cm.cm import cm_burst_pallas
+    from repro.kernels.fused.fused import chain_suffix_sums_pallas
+    from repro.kernels.screen.screen import screen_fused_pallas
+    X = jnp.asarray(rng.normal(size=(16, 128)))            # f64 under x64
+    y = jnp.asarray(rng.normal(size=16))
+    k = 8
+    calls = {
+        "screen": lambda interp: screen_fused_pallas(
+            X, y, jnp.linalg.norm(X, axis=0), jnp.zeros(128, bool), 0.3,
+            h=4, interpret=interp),
+        "cm": lambda interp: cm_burst_pallas(
+            X[:, :k], y, jnp.zeros(k), jnp.ones(k), jnp.ones(k, bool),
+            jnp.arange(k), 0.1, 1, k, interpret=interp),
+        "chain": lambda interp: chain_suffix_sums_pallas(
+            X, interpret=interp),
+    }
+    with pytest.raises(TypeError, match="float64"):
+        calls[kernel](False)
+    calls[kernel](True)                  # the interpreter runs it

@@ -177,6 +177,29 @@ def test_breaker_durably_degrades_backend(rng):
             srv.solve(Scalar(0.3 * lmax))
 
 
+@pytest.mark.parametrize("message", [
+    "INTERNAL: Mosaic failed to compile TPU kernel: failed to legalize",
+    "RESOURCE_EXHAUSTED: Out of memory while trying to allocate",
+])
+def test_deterministic_faults_are_not_retried_or_degraded(rng, message):
+    """A lowering/compile or out-of-memory error recurs on every attempt:
+    it surfaces at once as a BackendFault carrying the compiler's message
+    — no retry, no breaker, no silent fall back to the jnp backend."""
+    import jax
+    X, y, lmax = _problem(rng, n=30, p=80)
+    cfg = SaifConfig(eps=1e-7, screen_backend="pallas")
+    srv = open_serving(Problem(X=X, y=y), cfg,
+                       serving=ServingConfig(backoff_base_s=0.0))
+    with FaultInjector(fail_at={1, 2, 3}, exc=jax.errors.JaxRuntimeError,
+                       message=message) as inj:
+        with pytest.raises(BackendFault, match=message.split(":")[0]):
+            srv.solve(Scalar(0.3 * lmax))
+    assert len(inj.log) == 1                     # one attempt, no retries
+    assert not srv.breaker_open
+    assert srv.session.config.screen_backend == "pallas"
+    assert srv.stats().retries == 0
+
+
 def test_deadline_is_typed(rng):
     X, y, lmax = _problem(rng, n=30, p=80)
     srv = open_serving(Problem(X=X, y=y), SaifConfig(eps=1e-7))
