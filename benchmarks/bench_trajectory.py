@@ -1,6 +1,5 @@
-"""Fig. 3 reproduction: active-set size and dual objective D(theta_t)
-trajectories for SAIF — |A_t| must grow from a small seed to ~|support|,
-and D(theta_t) must decrease monotonically (Theorem 1/3)."""
+"""Fig. 3 reproduction: the active-set size trajectory of SAIF — |A_t|
+must grow from a small seed to ~|support| (Theorem 1/3)."""
 from __future__ import annotations
 
 import numpy as np
@@ -19,21 +18,15 @@ def run(full: bool = False):
     for frac in (0.1, 0.02):
         res = saif(X, y, frac * lmax, SaifConfig(eps=1e-8))
         tr_n = np.asarray(res.trace_n_active)
-        tr_d = np.asarray(res.trace_dual)
-        valid = tr_n >= 0
-        tr_n, tr_d = tr_n[valid], tr_d[valid]
+        tr_n = tr_n[tr_n >= 0]
         beta_ref = solve_lasso_cm(loss, jnp.asarray(X), jnp.asarray(y),
                                   frac * lmax, tol=1e-10)
         sup = int(np.sum(np.abs(np.asarray(beta_ref)) > 1e-9))
-        # D decreases after the initial ramp (allow tiny float noise)
-        dual_drops = np.all(np.diff(tr_d) <= np.abs(tr_d[:-1]) * 1e-6 + 1e-9)
         rows.append({"lam_frac": frac, "start_size": int(tr_n[0]),
                      "peak_size": int(tr_n.max()), "opt_support": sup,
-                     "n_outer": int(res.n_outer),
-                     "dual_monotone": bool(dual_drops)})
+                     "n_outer": int(res.n_outer)})
         print(f"[fig3] lam={frac}*lmax start={tr_n[0]:.0f} "
-              f"peak={tr_n.max():.0f} support={sup} "
-              f"dual_monotone={dual_drops}")
+              f"peak={tr_n.max():.0f} support={sup}")
     return rows
 
 

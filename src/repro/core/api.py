@@ -820,9 +820,11 @@ class Session:
         if self._pad_to is not None:
             import jax
             from repro.core.batch import pad_fleet_prep, prepare_fleet
-            fprep = prepare_fleet(self.problem.X, req.Y, self.config,
-                                  weights=req.weights)
-            fprep = pad_fleet_prep(fprep, *self._pad_to)
+            from repro.runtime.spans import span
+            with span("repro.session.prepare"):
+                fprep = prepare_fleet(self.problem.X, req.Y, self.config,
+                                      weights=req.weights)
+                fprep = pad_fleet_prep(fprep, *self._pad_to)
             res = fleet_solve(None, None, req.lams, self.config,
                               screen_fn=req.screen_fn, prep=fprep)
             return res._replace(beta=res.beta[:, :self._p_real])

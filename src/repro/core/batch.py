@@ -73,6 +73,7 @@ from repro.core.screen_backend import (SCREEN_RULES, BatchScreenFn,
                                        resolve_batch_screen,
                                        resolve_screen_rule)
 from repro.runtime.inject import seam as _fault_seam
+from repro.runtime.spans import span
 
 
 class _BatchState(NamedTuple):
@@ -86,7 +87,6 @@ class _BatchState(NamedTuple):
     inner: InnerCarry   # batched inner carry
     trace_n_active: jax.Array   # (B, max_outer)
     trace_gap: jax.Array
-    trace_dual: jax.Array
     trace_screened: jax.Array   # (B, max_outer) int32 observability (ISSUE 9)
     trace_survivors: jax.Array
     trace_post_viol: jax.Array
@@ -163,7 +163,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         delta=jnp.asarray(delta0, X.dtype),
         is_add=jnp.ones((b,), bool), stop=jnp.zeros((b,), bool),
         t=jnp.zeros((b,), jnp.int32), inner=inner0,
-        trace_n_active=trace0, trace_gap=trace0, trace_dual=trace0,
+        trace_n_active=trace0, trace_gap=trace0,
         trace_screened=itrace0, trace_survivors=itrace0,
         trace_post_viol=itrace0)
     # per-problem serial Newton polish (hybrid rule): rides inside the
@@ -219,17 +219,12 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         corr_act = jnp.abs(Xa_b.T @ ball.center)
         norm_act = jnp.where(mask_b, jnp.take(cn_b, idx_b), 0.0)
         del_row = mask_b & (corr_act + norm_act * ball.radius < 1.0)
-        conj = loss.conj(-lam_b * theta_b, y_b)
-        if w_b is not None:
-            conj = w_b * conj
-        dual_val = -jnp.sum(conj)
         if screen_rule.add_bound == "point":
             # strong-rule ADD geometry (DESIGN.md §13): radius 0
             r_eff_b = jnp.zeros_like(ball.radius)
         else:
             r_eff_b = delta_b * ball.radius
-        return (ball.center, r_eff_b, stop_now_b, del_row,
-                dual_val, ball.radius)
+        return (ball.center, r_eff_b, stop_now_b, del_row, ball.radius)
 
     def body(s: _BatchState) -> _BatchState:
         live = ~s.stop & (s.t < max_outer)       # (B,) frozen problems coast
@@ -254,24 +249,29 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                     w_b = None
 
                 def live_branch(_):
-                    Xa_b = aset_lib.gather_columns(X, aset_b)
-                    be = inner.make_one(y_b, w_b)
-                    carry2 = be.refresh(carry_b, aset_b, Xa_b)
-                    out = be.run(carry2, aset_b, Xa_b, lam_b, nep_b)
+                    with jax.named_scope("cm"):
+                        Xa_b = aset_lib.gather_columns(X, aset_b)
+                        be = inner.make_one(y_b, w_b)
+                        carry2 = be.refresh(carry_b, aset_b, Xa_b)
+                        out = be.run(carry2, aset_b, Xa_b, lam_b, nep_b)
                     beta_b = out.beta
                     zo_b = out.z
                     theta_b = out.theta
                     gapo_b = jnp.asarray(out.gap, X.dtype)
                     if newton:
-                        beta_b, zo_b, theta_b, gapo_b = jax.lax.cond(
-                            ~is_add_b,
-                            lambda a: _newton_one(carry2, aset_b.mask,
-                                                  Xa_b, y_b, w_b, lam_b,
-                                                  a),
-                            lambda a: a, (beta_b, zo_b, theta_b, gapo_b))
-                    cert = _certify(y_b, w_b, theta_b, gapo_b, lam_b,
-                                    eps_b, delta_b, is_add_b, Xa_b,
-                                    aset_b.idx, aset_b.mask, cn_b, c0_b)
+                        with jax.named_scope("cm"):
+                            beta_b, zo_b, theta_b, gapo_b = jax.lax.cond(
+                                ~is_add_b,
+                                lambda a: _newton_one(carry2, aset_b.mask,
+                                                      Xa_b, y_b, w_b,
+                                                      lam_b, a),
+                                lambda a: a,
+                                (beta_b, zo_b, theta_b, gapo_b))
+                    with jax.named_scope("gap"):
+                        cert = _certify(y_b, w_b, theta_b, gapo_b, lam_b,
+                                        eps_b, delta_b, is_add_b, Xa_b,
+                                        aset_b.idx, aset_b.mask, cn_b,
+                                        c0_b)
                     return (beta_b, zo_b, gapo_b, carry2) + cert
 
                 def frozen_branch(_):
@@ -281,7 +281,6 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                             jnp.zeros((), X.dtype),
                             jnp.asarray(True),
                             jnp.zeros((k,), bool),
-                            jnp.zeros((), X.dtype),
                             jnp.zeros((), X.dtype))
 
                 return jax.lax.cond(live_b, live_branch, frozen_branch,
@@ -292,13 +291,15 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             if has_weights:
                 xs = (live, Y, weights) + xs[2:]
             (beta, z, gap, inner_carry, theta_c, r_eff, stop_now, del_row,
-             dual_val, r_del) = jax.lax.map(solve_one, xs)
+             r_del) = jax.lax.map(solve_one, xs)
         else:
             # --- fleet-step path (the pallas problem-gridded kernel): the
             # backend owns the whole fleet's bursts in one launch, then
             # the per-problem certificate map runs (liveness-gated,
             # gathering each live problem's block like the serial body).
-            out, inner_carry = inner.fleet_step(s.inner, aset, lam, n_ep)
+            with jax.named_scope("cm"):
+                out, inner_carry = inner.fleet_step(s.inner, aset, lam,
+                                                    n_ep)
             beta = jnp.where(live[:, None], out.beta, aset.beta)
             z = jnp.where(live[:, None], out.z, s.z)
             gap = jnp.where(live, jnp.asarray(out.gap, X.dtype), s.gap)
@@ -323,8 +324,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                     k = aset_b.mask.shape[0]
                     return (jnp.zeros_like(theta_b),
                             jnp.zeros((), X.dtype), jnp.asarray(True),
-                            jnp.zeros((k,), bool), jnp.zeros((), X.dtype),
-                            jnp.zeros((), X.dtype))
+                            jnp.zeros((k,), bool), jnp.zeros((), X.dtype))
 
                 return jax.lax.cond(live_b, live_branch, frozen_branch,
                                     None)
@@ -333,15 +333,17 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                   aset, col_norm, c0)
             if has_weights:
                 xs = (live, Y, weights) + xs[2:]
-            (theta_c, r_eff, stop_now, del_row, dual_val,
-             r_del) = jax.lax.map(certify_one, xs)
+            with jax.named_scope("gap"):
+                (theta_c, r_eff, stop_now, del_row,
+                 r_del) = jax.lax.map(certify_one, xs)
 
         aset = aset._replace(beta=beta)
 
         # --- DEL (per-problem gap-safe rule) ------------------------------
         deleting = live & ~stop_now
         del_mask = del_row & deleting[:, None]
-        aset = aset_lib.delete_features_batch(aset, del_mask)
+        with jax.named_scope("add_delete"):
+            aset = aset_lib.delete_features_batch(aset, del_mask)
 
         # --- ADD phase (skipped fleet-wide once every problem is done) ----
         if screen_rule.add_bound == "point":
@@ -354,7 +356,9 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
 
         def do_add_phase(args):
             aset, delta, is_add = args
-            out: ScreenOut = screen(theta_c, r_eff, aset.in_active, do_add)
+            with jax.named_scope("screen"):
+                out: ScreenOut = screen(theta_c, r_eff, aset.in_active,
+                                        do_add)
             add_done = out.max_ub < 1.0                       # (B,)
             n_sur_scr = _n_surv32_batch(out, b)
             n_scr_scr = (jnp.sum(~aset.in_active, axis=1).astype(jnp.int32)
@@ -373,8 +377,9 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             # the top-scoring one
             keep = keep | _stuck_recruits(out, col_norm, r_eff, gap, eps)
             adding = do_add & ~add_done
-            aset = aset_lib.add_features_batch(aset, out.cand_idx,
-                                               keep & adding[:, None])
+            with jax.named_scope("add_delete"):
+                aset = aset_lib.add_features_batch(aset, out.cand_idx,
+                                                   keep & adding[:, None])
             done = do_add & add_done
             if screen_rule.delta_ramp:
                 grown = jnp.minimum(10.0 * delta, 1.0)
@@ -402,8 +407,9 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             do_check = live & stop_now
 
             def check(a):
-                chk: ScreenOut = screen(theta_c, r_del, a.in_active,
-                                        do_check)
+                with jax.named_scope("screen"):
+                    chk: ScreenOut = screen(theta_c, r_del, a.in_active,
+                                            do_check)
                 viol = do_check & (chk.max_ub >= 1.0)         # (B,)
                 ub_c = (chk.cand_score +
                         jnp.take_along_axis(col_norm, chk.cand_idx, axis=1)
@@ -412,8 +418,9 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                         (ub_c >= 1.0))
                 keep = keep.at[:, 0].set(
                     viol & jnp.isfinite(chk.cand_score[:, 0]))
-                return (aset_lib.add_features_batch(a, chk.cand_idx, keep),
-                        jnp.where(do_check, viol.astype(jnp.int32), -1))
+                with jax.named_scope("add_delete"):
+                    a = aset_lib.add_features_batch(a, chk.cand_idx, keep)
+                return a, jnp.where(do_check, viol.astype(jnp.int32), -1)
 
             def no_check(a):
                 return a, neg1
@@ -432,8 +439,6 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             trace_n_active=s.trace_n_active.at[barange, s.t].set(
                 n_act, mode="drop"),
             trace_gap=s.trace_gap.at[barange, s.t].set(gap, mode="drop"),
-            trace_dual=s.trace_dual.at[barange, s.t].set(
-                dual_val, mode="drop"),
             trace_screened=s.trace_screened.at[barange, s.t].set(
                 n_scr, mode="drop"),
             trace_survivors=s.trace_survivors.at[barange, s.t].set(
@@ -449,7 +454,6 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                       overflowed=final.aset.overflowed,
                       trace_n_active=final.trace_n_active,
                       trace_gap=final.trace_gap,
-                      trace_dual=final.trace_dual,
                       active_idx=final.aset.idx,
                       active_mask=final.aset.mask,
                       inner=final.inner,
@@ -681,7 +685,7 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         delta=jnp.asarray(delta0, X.dtype),
         is_add=jnp.ones((b,), bool), stop=jnp.zeros((b,), bool),
         t=jnp.zeros((b,), jnp.int32), inner=carry0,
-        trace_n_active=trace0, trace_gap=trace0, trace_dual=trace0,
+        trace_n_active=trace0, trace_gap=trace0,
         trace_screened=itrace0, trace_survivors=itrace0,
         trace_post_viol=itrace0)
 
@@ -706,10 +710,6 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         norm_act = jnp.where(mask_b, jnp.take(cn_b, idx_b), 0.0)
         r_del = widened_radius(ball.radius, ball.center, gamma_work)
         del_row = mask_b & (corr_act + norm_act * r_del < 1.0)
-        conj = loss.conj(-lam_b * theta_b, y_b)
-        if w_b is not None:
-            conj = w_b * conj
-        dual_val = -jnp.sum(conj)
         if screen_rule.add_bound == "point":
             # strong-rule ADD at radius 0: the mixed-precision screen
             # widens whatever radius it is handed by its own certified
@@ -721,8 +721,7 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             r_eff_b = delta_b * ball.radius
         # the raw safe radius rides along for the post-check screen, which
         # re-applies the dtype-appropriate widening internally
-        return (ball.center, r_eff_b, stop_now_b, del_row,
-                dual_val, ball.radius)
+        return (ball.center, r_eff_b, stop_now_b, del_row, ball.radius)
 
     if has_weights:
         certify = jax.vmap(_certify_one)
@@ -745,7 +744,8 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         n_ep = jnp.where(live, n_ep, 0).astype(jnp.int32)
 
         # --- lockstep inner burst (Gram form; LS-only by dispatch) -------
-        Xa = aset_lib.gather_columns_batch(X, aset)      # (B, n, k)
+        with jax.named_scope("cm"):
+            Xa = aset_lib.gather_columns_batch(X, aset)  # (B, n, k)
         # polish bodies (post-ADD) mutate nothing but masks, so the
         # h-column Gram reconcile is skipped fleet-wide when no slot is
         # dirty; dead slots still drop their feature id (gidx=-1) so a
@@ -753,18 +753,22 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         # row was zeroed by neighbours' refreshes while the slot was dead
         gidx2 = jnp.where(aset.mask, s.inner.gidx, -1)
         any_dirty = jnp.any(aset.mask & (gidx2 != aset.idx))
-        carry2 = jax.lax.cond(
-            any_dirty,
-            lambda c: _gram_refresh_fast(X, Y, weights, c, aset, Xa, h),
-            lambda c: c._replace(gidx=gidx2),
-            s.inner)
-        beta = _gram_sweep_fast(carry2.G, carry2.rho, aset.beta, aset.mask,
-                                lam, n_ep, smoothness=loss.smoothness)
-        z = jnp.einsum("bnk,bk->bn", Xa, beta)
-        if has_weights:
-            theta, gap = dual_gap(Xa, Y, beta, z, aset.mask, lam, weights)
-        else:
-            theta, gap = dual_gap(Xa, Y, beta, z, aset.mask, lam)
+        with jax.named_scope("cm"):
+            carry2 = jax.lax.cond(
+                any_dirty,
+                lambda c: _gram_refresh_fast(X, Y, weights, c, aset, Xa, h),
+                lambda c: c._replace(gidx=gidx2),
+                s.inner)
+            beta = _gram_sweep_fast(carry2.G, carry2.rho, aset.beta,
+                                    aset.mask, lam, n_ep,
+                                    smoothness=loss.smoothness)
+            z = jnp.einsum("bnk,bk->bn", Xa, beta)
+        with jax.named_scope("gap"):
+            if has_weights:
+                theta, gap = dual_gap(Xa, Y, beta, z, aset.mask, lam,
+                                      weights)
+            else:
+                theta, gap = dual_gap(Xa, Y, beta, z, aset.mask, lam)
         gap = jnp.asarray(gap, X.dtype)
 
         # --- fleet Newton polish (hybrid rule, DESIGN.md §13) -------------
@@ -800,27 +804,30 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                         jnp.where(better[:, None], th_n, theta_cc),
                         jnp.where(better, gap_n, gap_c))
 
-            beta, z, theta, gap = jax.lax.cond(
-                jnp.any(polishing), newton_fleet, lambda a: a,
-                (beta, z, theta, gap))
+            with jax.named_scope("cm"):
+                beta, z, theta, gap = jax.lax.cond(
+                    jnp.any(polishing), newton_fleet, lambda a: a,
+                    (beta, z, theta, gap))
 
-        if has_weights:
-            (theta_c, r_eff, stop_now, del_row, dual_val,
-             r_del_raw) = certify(
-                Y, weights, theta, gap, lam, eps, s.delta, s.is_add, Xa,
-                aset.idx, aset.mask, col_norm, c0)
-        else:
-            (theta_c, r_eff, stop_now, del_row, dual_val,
-             r_del_raw) = certify(
-                Y, theta, gap, lam, eps, s.delta, s.is_add, Xa,
-                aset.idx, aset.mask, col_norm, c0)
+        with jax.named_scope("gap"):
+            if has_weights:
+                (theta_c, r_eff, stop_now, del_row,
+                 r_del_raw) = certify(
+                    Y, weights, theta, gap, lam, eps, s.delta, s.is_add,
+                    Xa, aset.idx, aset.mask, col_norm, c0)
+            else:
+                (theta_c, r_eff, stop_now, del_row,
+                 r_del_raw) = certify(
+                    Y, theta, gap, lam, eps, s.delta, s.is_add, Xa,
+                    aset.idx, aset.mask, col_norm, c0)
 
         aset = aset._replace(beta=beta)
 
         # --- DEL (per-problem widened gap-safe rule) ----------------------
         deleting = live & ~stop_now
         del_mask = del_row & deleting[:, None]
-        aset = _delete_features_fast(aset, del_mask)
+        with jax.named_scope("add_delete"):
+            aset = _delete_features_fast(aset, del_mask)
 
         # --- ADD phase (skipped fleet-wide once every problem is done) ----
         if screen_rule.add_bound == "point":
@@ -830,7 +837,9 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
 
         def do_add_phase(args):
             aset, delta, is_add = args
-            out: ScreenOut = screen(theta_c, r_eff, aset.in_active, do_add)
+            with jax.named_scope("screen"):
+                out: ScreenOut = screen(theta_c, r_eff, aset.in_active,
+                                        do_add)
             add_done = out.max_ub < 1.0                  # (B,)
             n_sur_scr = _n_surv32_batch(out, b)
             n_scr_scr = (jnp.sum(~aset.in_active, axis=1).astype(jnp.int32)
@@ -845,8 +854,9 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             keep = jnp.cumprod(keep.astype(jnp.int32), axis=1).astype(bool)
             keep = keep | _stuck_recruits(out, col_norm, r_eff, gap, eps)
             adding = do_add & ~add_done
-            aset = _add_features_fast(aset, out.cand_idx,
-                                      keep & adding[:, None])
+            with jax.named_scope("add_delete"):
+                aset = _add_features_fast(aset, out.cand_idx,
+                                          keep & adding[:, None])
             done = do_add & add_done
             if screen_rule.delta_ramp:
                 grown = jnp.minimum(10.0 * delta, 1.0)
@@ -873,8 +883,9 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             do_check = live & stop_now
 
             def check(a):
-                chk: ScreenOut = screen(theta_c, r_del_raw, a.in_active,
-                                        do_check)
+                with jax.named_scope("screen"):
+                    chk: ScreenOut = screen(theta_c, r_del_raw,
+                                            a.in_active, do_check)
                 viol = do_check & (chk.max_ub >= 1.0)
                 ub_c = (chk.cand_score +
                         jnp.take_along_axis(col_norm, chk.cand_idx, axis=1)
@@ -883,8 +894,9 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                         (ub_c >= 1.0))
                 keep = keep.at[:, 0].set(
                     viol & jnp.isfinite(chk.cand_score[:, 0]))
-                return (_add_features_fast(a, chk.cand_idx, keep),
-                        jnp.where(do_check, viol.astype(jnp.int32), -1))
+                with jax.named_scope("add_delete"):
+                    a = _add_features_fast(a, chk.cand_idx, keep)
+                return a, jnp.where(do_check, viol.astype(jnp.int32), -1)
 
             def no_check(a):
                 return a, neg1
@@ -903,8 +915,6 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             trace_n_active=s.trace_n_active.at[barange, s.t].set(
                 n_act, mode="drop"),
             trace_gap=s.trace_gap.at[barange, s.t].set(gap, mode="drop"),
-            trace_dual=s.trace_dual.at[barange, s.t].set(
-                dual_val, mode="drop"),
             trace_screened=s.trace_screened.at[barange, s.t].set(
                 n_scr, mode="drop"),
             trace_survivors=s.trace_survivors.at[barange, s.t].set(
@@ -920,7 +930,6 @@ def _saif_batch_fast_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                       overflowed=final.aset.overflowed,
                       trace_n_active=final.trace_n_active,
                       trace_gap=final.trace_gap,
-                      trace_dual=final.trace_dual,
                       active_idx=final.aset.idx,
                       active_mask=final.aset.mask,
                       inner=final.inner,
@@ -996,7 +1005,8 @@ def prepare_fleet(X, Y, config: SaifConfig, weights=None) -> FleetPrep:
         W_arg = W if W is not None else jnp.zeros((1, 1), X.dtype)
         c0, col_norm, c0_max, c0_med = _prepare_fleet_fast_jit(
             X, Y, W_arg, loss_name=config.loss, has_w=W is not None)
-        c0_max, c0_med = jax.device_get((c0_max, c0_med))
+        with span("repro.sync.fleet_stats"):
+            c0_max, c0_med = jax.device_get((c0_max, c0_med))
         return FleetPrep(X=X, Y=Y, W=W, c0=c0, col_norm=col_norm,
                          c0_max=[float(v) for v in c0_max],
                          c0_median=[float(v) for v in c0_med])
@@ -1017,8 +1027,9 @@ def prepare_fleet(X, Y, config: SaifConfig, weights=None) -> FleetPrep:
                                     c0.shape)
     else:
         col_norm = jnp.sqrt(W @ (X * X))                   # (B, p)
-    c0_max, c0_med = jax.device_get(
-        (jnp.max(c0, axis=1), jnp.median(c0, axis=1)))
+    stats = (jnp.max(c0, axis=1), jnp.median(c0, axis=1))
+    with span("repro.sync.fleet_stats"):
+        c0_max, c0_med = jax.device_get(stats)
     return FleetPrep(X=X, Y=Y, W=W, c0=c0, col_norm=col_norm,
                      c0_max=[float(v) for v in c0_max],
                      c0_median=[float(v) for v in c0_med])
@@ -1185,94 +1196,104 @@ def fleet_solve(X, Y, lam, config: SaifConfig = SaifConfig(),
         raise NotImplementedError(
             "saif_batch solves plain-LASSO fleets; the fused unpenalized "
             "slot is serial-only for now (DESIGN.md §8)")
-    if prep is None:
-        prep = prepare_fleet(X, Y, config, weights=weights)
-    X, Y, W = prep.X, prep.Y, prep.W
-    n, p = X.shape
-    n_eff = prep.n_true or n
-    p_eff = prep.p_true or p
-    pad_mask = (jnp.arange(p) >= p_eff) if p_eff < p else None
-    b = Y.shape[0]
-    lam_arr = jnp.broadcast_to(
-        jnp.asarray(lam, X.dtype).reshape(-1), (b,))
-    lams = [float(v) for v in jax.device_get(lam_arr)]
-    rule = resolve_screen_rule(config.screen_rule)
-    use_seq = config.use_seq_ball and W is None and rule.use_seq_ball
-    backend = resolve_batch_screen(config.screen_backend, b=b, p=p_eff,
-                                   dtype=X.dtype)
-    # parity="fast" dispatch (DESIGN.md §11): the lockstep engine is
-    # least-squares only (its inner burst is the batched Gram sweep) and
-    # a custom screen_fn owns its own scores — both fall back to the
-    # bitwise engine, which is always a valid (slower) implementation of
-    # the same contract.
-    use_fast = (config.parity == "fast"
-                and config.loss == "least_squares"
-                and screen_fn is None)
+    # per-request preparation: statistics, h, capacity, cold start
+    with span("repro.session.prepare"):
+        if prep is None:
+            prep = prepare_fleet(X, Y, config, weights=weights)
+        X, Y, W = prep.X, prep.Y, prep.W
+        n, p = X.shape
+        n_eff = prep.n_true or n
+        p_eff = prep.p_true or p
+        pad_mask = (jnp.arange(p) >= p_eff) if p_eff < p else None
+        b = Y.shape[0]
+        lam_arr = jnp.broadcast_to(
+            jnp.asarray(lam, X.dtype).reshape(-1), (b,))
+        with span("repro.sync.lams"):
+            lams = [float(v) for v in jax.device_get(lam_arr)]
+        rule = resolve_screen_rule(config.screen_rule)
+        use_seq = config.use_seq_ball and W is None and rule.use_seq_ball
+        backend = resolve_batch_screen(config.screen_backend, b=b, p=p_eff,
+                                       dtype=X.dtype)
+        # parity="fast" dispatch (DESIGN.md §11): the lockstep engine is
+        # least-squares only (its inner burst is the batched Gram sweep)
+        # and a custom screen_fn owns its own scores — both fall back to
+        # the bitwise engine, which is always a valid (slower)
+        # implementation of the same contract.
+        use_fast = (config.parity == "fast"
+                    and config.loss == "least_squares"
+                    and screen_fn is None)
 
-    hs, h = fleet_batch_sizes(prep, lams, config)
-    h_tilde = jnp.asarray(
-        [max(int(math.ceil(config.zeta * h_b)), 1) for h_b in hs],
-        jnp.int32)
-    h_cap = jnp.asarray(hs, jnp.int32)
-    k_max = config.k_max or default_capacity(h, p_eff)
-    delta0 = jnp.asarray(_delta0s(prep, lams, config), X.dtype)
-    W_arg = W if W is not None else jnp.zeros((1, 1), X.dtype)
+        hs, h = fleet_batch_sizes(prep, lams, config)
+        h_tilde = jnp.asarray(
+            [max(int(math.ceil(config.zeta * h_b)), 1) for h_b in hs],
+            jnp.int32)
+        h_cap = jnp.asarray(hs, jnp.int32)
+        k_max = config.k_max or default_capacity(h, p_eff)
+        delta0 = jnp.asarray(_delta0s(prep, lams, config), X.dtype)
+        W_arg = W if W is not None else jnp.zeros((1, 1), X.dtype)
 
-    # cold start computed ONCE at the original capacity: like the serial
-    # driver, elastic growth pads the buffers but keeps the original
-    # (possibly capacity-truncated) initial support, so a re-entered fleet
-    # reproduces the serial overflow-recovery trajectories bitwise
-    if use_fast:
-        sel_dt = (None if config.screen_dtype == "working"
-                  else jnp.dtype(jnp.float32))
-        init_idx, init_beta, init_mask = _initial_support_batch_jit(
-            prep.c0, hs=tuple(hs), k_max=k_max, p=p_eff, dtype=X.dtype,
-            sel_dtype=sel_dt)
-    else:
-        init_idx, init_beta, init_mask = initial_support_batch(
-            prep.c0, hs, k_max, p_eff, X.dtype)
-    while True:
-        pad = k_max - init_idx.shape[1]
-        if pad > 0:
-            init_idx = jnp.pad(init_idx, ((0, 0), (0, pad)))
-            init_beta = jnp.pad(init_beta, ((0, 0), (0, pad)))
-            init_mask = jnp.pad(init_mask, ((0, 0), (0, pad)))
-        # the fleet dispatch routes through the fault-injection seam
-        # (repro.runtime.inject) — a single None-check when disarmed
+        # cold start computed ONCE at the original capacity: like the
+        # serial driver, elastic growth pads the buffers but keeps the
+        # original (possibly capacity-truncated) initial support, so a
+        # re-entered fleet reproduces the serial overflow-recovery
+        # trajectories bitwise
         if use_fast:
-            km = k_max
-            res = _fault_seam("fleet", lambda: _saif_batch_fast_jit(
-                X, Y, W_arg, prep.col_norm, prep.c0, lam_arr,
-                jnp.full((b,), config.eps, X.dtype), delta0,
-                init_idx, init_beta, init_mask, h_tilde, h_cap,
-                pad_mask,
-                loss_name=config.loss, h=h, k_max=km,
-                inner_epochs=config.inner_epochs,
-                polish_factor=config.polish_factor,
-                max_outer=config.max_outer, use_seq_ball=use_seq,
-                screen_dtype=config.screen_dtype,
-                has_weights=W is not None, screen_rule=rule))
+            sel_dt = (None if config.screen_dtype == "working"
+                      else jnp.dtype(jnp.float32))
+            init_idx, init_beta, init_mask = _initial_support_batch_jit(
+                prep.c0, hs=tuple(hs), k_max=k_max, p=p_eff, dtype=X.dtype,
+                sel_dtype=sel_dt)
         else:
-            inner = resolve_batch_inner(config, n_eff, k_max, b, X.dtype)
-            carry = cold_inner_carry_batch(b, k_max, X.dtype, backend=inner)
-            res = _fault_seam("fleet", lambda: _saif_batch_jit(
-                X, Y, W_arg, prep.col_norm, prep.c0, lam_arr,
-                jnp.full((b,), config.eps, X.dtype), delta0,
-                init_idx, init_beta, init_mask,
-                carry.G, carry.rho, carry.gidx, h_tilde, h_cap,
-                pad_mask,
-                loss_name=config.loss, h=h, k_max=k_max,
-                inner_epochs=config.inner_epochs,
-                polish_factor=config.polish_factor,
-                max_outer=config.max_outer, use_seq_ball=use_seq,
-                screen_backend=backend, inner_backend=inner,
-                has_weights=W is not None, screen_fn=screen_fn,
-                screen_rule=rule))
-        # ONE host sync for the whole fleet's overflow flags; elastic
-        # growth re-enters cold at doubled capacity (per-problem results
-        # are capacity-invariant, so non-overflowing problems reproduce
-        # their previous answers bitwise)
-        if not bool(jnp.any(res.overflowed)) or k_max >= p_eff:
+            init_idx, init_beta, init_mask = initial_support_batch(
+                prep.c0, hs, k_max, p_eff, X.dtype)
+    while True:
+        # one engine dispatch through the read that waits for it
+        with span("repro.engine.run", b=b, h=h, k_max=k_max):
+            pad = k_max - init_idx.shape[1]
+            if pad > 0:
+                init_idx = jnp.pad(init_idx, ((0, 0), (0, pad)))
+                init_beta = jnp.pad(init_beta, ((0, 0), (0, pad)))
+                init_mask = jnp.pad(init_mask, ((0, 0), (0, pad)))
+            # the fleet dispatch routes through the fault-injection seam
+            # (repro.runtime.inject) — a single None-check when disarmed
+            if use_fast:
+                km = k_max
+                res = _fault_seam("fleet", lambda: _saif_batch_fast_jit(
+                    X, Y, W_arg, prep.col_norm, prep.c0, lam_arr,
+                    jnp.full((b,), config.eps, X.dtype), delta0,
+                    init_idx, init_beta, init_mask, h_tilde, h_cap,
+                    pad_mask,
+                    loss_name=config.loss, h=h, k_max=km,
+                    inner_epochs=config.inner_epochs,
+                    polish_factor=config.polish_factor,
+                    max_outer=config.max_outer, use_seq_ball=use_seq,
+                    screen_dtype=config.screen_dtype,
+                    has_weights=W is not None, screen_rule=rule))
+            else:
+                inner = resolve_batch_inner(config, n_eff, k_max, b,
+                                            X.dtype)
+                carry = cold_inner_carry_batch(b, k_max, X.dtype,
+                                               backend=inner)
+                res = _fault_seam("fleet", lambda: _saif_batch_jit(
+                    X, Y, W_arg, prep.col_norm, prep.c0, lam_arr,
+                    jnp.full((b,), config.eps, X.dtype), delta0,
+                    init_idx, init_beta, init_mask,
+                    carry.G, carry.rho, carry.gidx, h_tilde, h_cap,
+                    pad_mask,
+                    loss_name=config.loss, h=h, k_max=k_max,
+                    inner_epochs=config.inner_epochs,
+                    polish_factor=config.polish_factor,
+                    max_outer=config.max_outer, use_seq_ball=use_seq,
+                    screen_backend=backend, inner_backend=inner,
+                    has_weights=W is not None, screen_fn=screen_fn,
+                    screen_rule=rule))
+            # ONE host sync for the whole fleet's overflow flags; elastic
+            # growth re-enters cold at doubled capacity (per-problem
+            # results are capacity-invariant, so non-overflowing problems
+            # reproduce their previous answers bitwise)
+            with span("repro.sync.overflow"):
+                overflowed = bool(jnp.any(res.overflowed))
+        if not overflowed or k_max >= p_eff:
             return res
         k_max = min(2 * k_max, p_eff)
 

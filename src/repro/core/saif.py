@@ -42,6 +42,7 @@ from repro.core.screen_backend import (ScreenFn, ScreenOut, ScreenRule,
                                        resolve_backend, resolve_screen_rule)
 from repro.core.screen_rule import SCREEN_RULES
 from repro.runtime.inject import seam as _fault_seam
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,7 +110,6 @@ class SaifResult(NamedTuple):
     overflowed: jax.Array    # capacity overflow flag
     trace_n_active: jax.Array  # (max_outer,) |A_t| per outer step (-1 pad)
     trace_gap: jax.Array       # (max_outer,)
-    trace_dual: jax.Array      # (max_outer,)
     # final slot state + inner-solver carry: the path engine hands these to
     # the next lambda so slot assignment (and the Gram buffers that are
     # indexed by it) survive the warm start (DESIGN.md §6)
@@ -137,7 +137,6 @@ class _State(NamedTuple):
     inner: InnerCarry   # inner-solver carry (Gram buffers for "gram")
     trace_n_active: jax.Array
     trace_gap: jax.Array
-    trace_dual: jax.Array
     trace_screened: jax.Array   # int32 screening counters (ISSUE 9)
     trace_survivors: jax.Array
     trace_post_viol: jax.Array
@@ -271,7 +270,7 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                     delta=jnp.asarray(delta0, X.dtype),
                     is_add=jnp.asarray(True), stop=jnp.asarray(False),
                     t=jnp.asarray(0), inner=inner0,
-                    trace_n_active=trace0, trace_gap=trace0, trace_dual=trace0,
+                    trace_n_active=trace0, trace_gap=trace0,
                     trace_screened=itrace0, trace_survivors=itrace0,
                     trace_post_viol=itrace0)
 
@@ -280,7 +279,8 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
 
     def body(s: _State) -> _State:
         aset = s.aset
-        Xa = aset_lib.gather_columns(X, aset)
+        with jax.named_scope("cm"):
+            Xa = aset_lib.gather_columns(X, aset)
 
         # --- K epochs of coordinate minimization on the sub-problem --------
         # (K * polish_factor once recruiting is done — §Perf iteration 2;
@@ -289,12 +289,13 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
         # The backend absorbs last step's ADD/DEL (bounded Gram column
         # refresh for "gram", no-op otherwise), runs the burst, and returns
         # the dual point + duality gap (Eq. 11) along with (beta, z).
-        inner_carry = inner.refresh(s.inner, aset, Xa)
         newton = (screen_rule.newton_polish and inner_backend == "gram"
                   and loss_name == "least_squares" and unpen_idx < 0)
         n_ep = jnp.where(s.is_add, inner_epochs,
                          inner_epochs * polish_factor)
-        out = inner.run(inner_carry, aset, Xa, lam, n_ep)
+        with jax.named_scope("cm"):
+            inner_carry = inner.refresh(s.inner, aset, Xa)
+            out = inner.run(inner_carry, aset, Xa, lam, n_ep)
         beta, z, theta = out.beta, out.z, out.theta
         gap = jnp.asarray(out.gap, X.dtype)
 
@@ -334,8 +335,10 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                         jnp.where(better, th_n, theta_c_),
                         jnp.where(better, gap_n, gap_c))
 
-            beta, z, theta, gap = jax.lax.cond(
-                ~s.is_add, newton_step, lambda a: a, (beta, z, theta, gap))
+            with jax.named_scope("cm"):
+                beta, z, theta, gap = jax.lax.cond(
+                    ~s.is_add, newton_step, lambda a: a,
+                    (beta, z, theta, gap))
         aset = aset._replace(beta=beta)
 
         # --- ball region from the backend's dual point (Thm 2 / Eq. 12) ----
@@ -344,15 +347,17 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
         # zero radius would let the strict DEL / ADD-stop comparisons evict
         # or ignore boundary features (|x^T theta*| = 1) on float noise —
         # the near-lambda_max gaussian-design support misses (ROADMAP item).
-        ball = gap_ball(loss, theta, gap, lam,
-                        floor=gap_precision_floor(theta, lam))
-        if use_seq_ball:
-            # lam_max(t) over the *active* features (paper Sec 2.2).
-            c0_active = jnp.where(aset.mask, jnp.take(c0, aset.idx), -jnp.inf)
-            lam0t = jnp.maximum(jnp.max(c0_active), lam * (1 + 1e-12))
-            theta0t = -g0 / lam0t
-            b_seq = sequential_ball(loss, y, theta0t, lam0t, lam)
-            ball = intersect_balls(b_seq, ball)
+        with jax.named_scope("gap"):
+            ball = gap_ball(loss, theta, gap, lam,
+                            floor=gap_precision_floor(theta, lam))
+            if use_seq_ball:
+                # lam_max(t) over the *active* features (paper Sec 2.2).
+                c0_active = jnp.where(aset.mask, jnp.take(c0, aset.idx),
+                                      -jnp.inf)
+                lam0t = jnp.maximum(jnp.max(c0_active), lam * (1 + 1e-12))
+                theta0t = -g0 / lam0t
+                b_seq = sequential_ball(loss, y, theta0t, lam0t, lam)
+                ball = intersect_balls(b_seq, ball)
         # delta shrinks the radius for the ADD-side rules only (its paper
         # role: avoid recruiting inaccurately-screened features early). DEL
         # keeps the full gap-safe radius: a delta-shrunk DEL can evict
@@ -373,16 +378,19 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
         stop_now = (~s.is_add) & (gap <= eps)
 
         # --- DEL (gap-safe rule on the sub-problem) ------------------------
-        corr_act = jnp.abs(Xa.T @ theta_c)                     # (k_max,)
-        norm_act = jnp.where(aset.mask, jnp.take(col_norm, aset.idx), 0.0)
-        del_mask = aset.mask & (corr_act + norm_act * r_del < 1.0)
+        with jax.named_scope("gap"):
+            corr_act = jnp.abs(Xa.T @ theta_c)                 # (k_max,)
+            norm_act = jnp.where(aset.mask, jnp.take(col_norm, aset.idx),
+                                 0.0)
+            del_mask = aset.mask & (corr_act + norm_act * r_del < 1.0)
         if unpen_idx >= 0:
             # the unpenalized slot is always resident: its dual constraint
             # is an equality (Thm 7), so the <1 DEL rule never applies
             del_mask = del_mask & (aset.idx != unpen_idx)
-        aset = jax.lax.cond(
-            stop_now, lambda a: a,
-            lambda a: aset_lib.delete_features(a, del_mask), aset)
+        with jax.named_scope("add_delete"):
+            aset = jax.lax.cond(
+                stop_now, lambda a: a,
+                lambda a: aset_lib.delete_features(a, del_mask), aset)
 
         # --- ADD phase ------------------------------------------------------
         def do_add_phase(args):
@@ -390,7 +398,8 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
             # One backend call covers the whole full-width decision: the
             # ADD-stop bound, the top-h candidates and their violation
             # counts. No (p,)-shaped sort, no second full-width pass.
-            out: ScreenOut = screen(theta_c, r_eff, aset.in_active)
+            with jax.named_scope("screen"):
+                out: ScreenOut = screen(theta_c, r_eff, aset.in_active)
             # stop criterion for ADD (Remark 1): max_{R_t} ub < 1
             add_done = out.max_ub < 1.0
             n_sur = _n_surv32(out)
@@ -444,8 +453,9 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                 return (aset_lib.add_features(aset, out.cand_idx, keep),
                         delta, is_add)
 
-            aset, delta, is_add = jax.lax.cond(add_done, on_done, on_add,
-                                               (aset, delta, is_add))
+            with jax.named_scope("add_delete"):
+                aset, delta, is_add = jax.lax.cond(
+                    add_done, on_done, on_add, (aset, delta, is_add))
             return aset, delta, is_add, n_scr, n_sur
 
         if screen_rule.add_bound == "point":
@@ -478,7 +488,8 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
         # safe certificate, so hybrid keeps the SAIF guarantee.
         if screen_rule.post_check:
             def check(a):
-                chk: ScreenOut = screen(theta_c, r_del, a.in_active)
+                with jax.named_scope("screen"):
+                    chk: ScreenOut = screen(theta_c, r_del, a.in_active)
                 viol = chk.max_ub >= 1.0
                 # recruit every candidate the safe ball cannot rule out;
                 # force slot 0 so a failed check always makes progress
@@ -489,8 +500,9 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                 keep = viol & jnp.isfinite(chk.cand_score) & (ub_c >= 1.0)
                 keep = keep.at[0].set(
                     viol & jnp.isfinite(chk.cand_score[0]))
-                return (aset_lib.add_features(a, chk.cand_idx, keep),
-                        viol.astype(jnp.int32))
+                with jax.named_scope("add_delete"):
+                    aset_c = aset_lib.add_features(a, chk.cand_idx, keep)
+                return aset_c, viol.astype(jnp.int32)
 
             def no_check(a):
                 return a, jnp.full((), -1, jnp.int32)
@@ -501,14 +513,12 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
             post_viol = jnp.full((), -1, jnp.int32)
             stop_final = stop_now
 
-        dual_val = loss.dual_objective(y, theta, lam)   # feasible point
         n_act = aset.count.astype(X.dtype)
         return _State(
             aset=aset, z=z, gap=gap, delta=delta, is_add=is_add,
             stop=stop_final, t=s.t + 1, inner=inner_carry,
             trace_n_active=s.trace_n_active.at[s.t].set(n_act),
             trace_gap=s.trace_gap.at[s.t].set(gap),
-            trace_dual=s.trace_dual.at[s.t].set(dual_val),
             trace_screened=s.trace_screened.at[s.t].set(n_scr),
             trace_survivors=s.trace_survivors.at[s.t].set(n_sur),
             trace_post_viol=s.trace_post_viol.at[s.t].set(post_viol))
@@ -520,7 +530,6 @@ def _saif_jit(X, y, col_norm, c0, lam, eps, delta0, init_idx, init_beta,
                       overflowed=final.aset.overflowed,
                       trace_n_active=final.trace_n_active,
                       trace_gap=final.trace_gap,
-                      trace_dual=final.trace_dual,
                       active_idx=final.aset.idx,
                       active_mask=final.aset.mask,
                       inner=final.inner,
@@ -582,12 +591,14 @@ def prepare_path(X, y, config: SaifConfig) -> PathState:
     from repro.core.duality import null_gradient
 
     loss = get_loss(config.loss)
-    X = jnp.asarray(X)
-    y = jnp.asarray(y)
-    _, c0, b0 = null_gradient(loss, X, y, config.unpen_idx)
-    col_norm = jnp.linalg.norm(X, axis=0)
-    c0_max, c0_median, b0 = jax.device_get(
-        (jnp.max(c0), jnp.median(c0), b0))
+    with span("repro.session.prepare"):
+        X = jnp.asarray(X)
+        y = jnp.asarray(y)
+        _, c0, b0 = null_gradient(loss, X, y, config.unpen_idx)
+        col_norm = jnp.linalg.norm(X, axis=0)
+        stats = (jnp.max(c0), jnp.median(c0), b0)
+        with span("repro.sync.path_stats"):
+            c0_max, c0_median, b0 = jax.device_get(stats)
     return PathState(X=X, y=y, c0=c0, col_norm=col_norm,
                      lam_max=float(c0_max), c0_max=float(c0_max),
                      c0_median=float(c0_median), b0=float(b0))
@@ -641,98 +652,109 @@ def solve_scalar(prep: PathState, lam: float,
     convenience; a session (``repro.core.api``) prepares once and calls
     this per request.
     """
-    X, y, c0, col_norm = prep.X, prep.y, prep.c0, prep.col_norm
-    n, p = X.shape
-    # Bucket-padded preparations (DESIGN.md §12): the arrays carry the
-    # bucket shape; every policy decision below runs on the real dims so
-    # padding can never change h, capacity, or a backend crossover.
-    n_true = prep.n_true or n
-    p_true = prep.p_true or p
-    pad_mask = (jnp.arange(p) >= p_true) if p_true < p else None
-    unpen = config.unpen_idx
-    lam_max = prep.lam_max
-    b0 = prep.b0
-    rule = resolve_screen_rule(config.screen_rule)
-    # The Thm-2 sequential ball assumes the all-penalized null dual
-    # theta0 = -f'(0)/lam_max — invalid once b is unpenalized (DESIGN.md
-    # §7), so the gap ball alone drives screening there. The rule gates it
-    # too: gap_safe/hybrid screen on the gap sphere alone (§13).
-    use_seq = config.use_seq_ball and unpen is None and rule.use_seq_ball
+    # per-request preparation: h, capacity, initial active set
+    with span("repro.session.prepare"):
+        X, y, c0, col_norm = prep.X, prep.y, prep.c0, prep.col_norm
+        n, p = X.shape
+        # Bucket-padded preparations (DESIGN.md §12): the arrays carry the
+        # bucket shape; every policy decision below runs on the real dims
+        # so padding can never change h, capacity, or a backend crossover.
+        n_true = prep.n_true or n
+        p_true = prep.p_true or p
+        pad_mask = (jnp.arange(p) >= p_true) if p_true < p else None
+        unpen = config.unpen_idx
+        lam_max = prep.lam_max
+        b0 = prep.b0
+        rule = resolve_screen_rule(config.screen_rule)
+        # The Thm-2 sequential ball assumes the all-penalized null dual
+        # theta0 = -f'(0)/lam_max — invalid once b is unpenalized
+        # (DESIGN.md §7), so the gap ball alone drives screening there. The
+        # rule gates it too: gap_safe/hybrid screen on the gap sphere alone
+        # (§13).
+        use_seq = config.use_seq_ball and unpen is None and rule.use_seq_ball
 
-    h = add_batch_size_static(config.c, lam, prep.c0_max, prep.c0_median,
-                              p_true)
-    h_tilde = max(int(math.ceil(config.zeta * h)), 1)
-    k_max = config.k_max or default_capacity(h, p_true)
-    delta0 = config.delta0 if config.delta0 is not None else \
-        min(max(lam / lam_max, 1e-3), 1.0)
-    backend = resolve_backend(config.screen_backend, X.dtype)
+        h = add_batch_size_static(config.c, lam, prep.c0_max, prep.c0_median,
+                                  p_true)
+        h_tilde = max(int(math.ceil(config.zeta * h)), 1)
+        k_max = config.k_max or default_capacity(h, p_true)
+        delta0 = config.delta0 if config.delta0 is not None else \
+            min(max(lam / lam_max, 1e-3), 1.0)
+        backend = resolve_backend(config.screen_backend, X.dtype)
 
-    # Initial active set: top-h' by |X^T f'(0)| (Algorithm 1 line 1),
-    # or a warm start from a neighbouring lambda (Sec 5.3 path mode).
-    # Always padded to (k_max,) so warm-started paths share one compilation.
-    if warm_idx is not None:
-        k_max = max(k_max, default_capacity(h, p_true))
-        if unpen is None:
-            # plain LASSO: stay on device, no host round-trip
-            n_init = min(int(warm_idx.shape[0]), k_max, p_true)
-            init_idx = jnp.zeros((k_max,), jnp.int32).at[:n_init].set(
-                jnp.asarray(warm_idx)[:n_init].astype(jnp.int32))
-            init_beta = jnp.zeros((k_max,), X.dtype)
-            if warm_beta is not None:
-                init_beta = init_beta.at[:n_init].set(
-                    jnp.asarray(warm_beta)[:n_init].astype(X.dtype))
+        # Initial active set: top-h' by |X^T f'(0)| (Algorithm 1 line 1),
+        # or a warm start from a neighbouring lambda (Sec 5.3 path mode).
+        # Always padded to (k_max,) so warm-started paths share one
+        # compilation.
+        if warm_idx is not None:
+            k_max = max(k_max, default_capacity(h, p_true))
+            if unpen is None:
+                # plain LASSO: stay on device, no host round-trip
+                n_init = min(int(warm_idx.shape[0]), k_max, p_true)
+                init_idx = jnp.zeros((k_max,), jnp.int32).at[:n_init].set(
+                    jnp.asarray(warm_idx)[:n_init].astype(jnp.int32))
+                init_beta = jnp.zeros((k_max,), X.dtype)
+                if warm_beta is not None:
+                    init_beta = init_beta.at[:n_init].set(
+                        jnp.asarray(warm_beta)[:n_init].astype(X.dtype))
+            else:
+                with span("repro.sync.warm_start"):
+                    warm_ids = [int(i) for i in jnp.asarray(warm_idx).tolist()]
+                with span("repro.sync.warm_start"):
+                    warm_vals = (list(jnp.asarray(warm_beta).tolist())
+                                 if warm_beta is not None
+                                 else [0.0] * len(warm_ids))
+                if unpen not in warm_ids:
+                    # the unpenalized slot is always resident, even when
+                    # the previous lambda left b exactly 0 — PREPEND it so
+                    # a capacity-full warm support can never truncate it
+                    # away
+                    warm_ids.insert(0, unpen)
+                    warm_vals.insert(0, float(b0))
+                n_init = min(len(warm_ids), k_max, p_true)
+                init_idx = jnp.zeros((k_max,), jnp.int32).at[:n_init].set(
+                    jnp.asarray(warm_ids[:n_init], jnp.int32))
+                init_beta = jnp.zeros((k_max,), X.dtype).at[:n_init].set(
+                    jnp.asarray(warm_vals[:n_init], X.dtype))
         else:
-            warm_ids = [int(i) for i in jnp.asarray(warm_idx).tolist()]
-            warm_vals = (list(jnp.asarray(warm_beta).tolist())
-                         if warm_beta is not None
-                         else [0.0] * len(warm_ids))
-            if unpen not in warm_ids:
-                # the unpenalized slot is always resident, even when the
-                # previous lambda left b exactly 0 — PREPEND it so a
-                # capacity-full warm support can never truncate it away
-                warm_ids.insert(0, unpen)
-                warm_vals.insert(0, float(b0))
-            n_init = min(len(warm_ids), k_max, p_true)
-            init_idx = jnp.zeros((k_max,), jnp.int32).at[:n_init].set(
-                jnp.asarray(warm_ids[:n_init], jnp.int32))
-            init_beta = jnp.zeros((k_max,), X.dtype).at[:n_init].set(
-                jnp.asarray(warm_vals[:n_init], X.dtype))
-    else:
-        init_idx, init_beta, n_init = initial_support(
-            c0, h, k_max, p_true, unpen, b0, X.dtype)
+            init_idx, init_beta, n_init = initial_support(
+                c0, h, k_max, p_true, unpen, b0, X.dtype)
 
     while True:
-        init_idx = init_idx[:k_max]
-        init_beta = init_beta[:k_max]
-        if init_idx.shape[0] < k_max:   # capacity grew after overflow
-            pad = k_max - init_idx.shape[0]
-            init_idx = jnp.pad(init_idx, (0, pad))
-            init_beta = jnp.pad(init_beta, (0, pad))
-        # capacity growth can move the auto crossover (DESIGN.md §6)
-        inner = resolve_inner_backend(config.inner_backend, config.loss,
-                                      n_true, k_max, X.dtype)
-        carry = cold_inner_carry(k_max, X.dtype, backend=inner)
-        # the engine dispatch routes through the fault-injection seam
-        # (repro.runtime.inject) — a single None-check when disarmed
-        res = _fault_seam("serial", lambda: _saif_jit(
-            X, y, col_norm, c0, jnp.asarray(lam, X.dtype),
-            jnp.asarray(config.eps, X.dtype),
-            delta0, init_idx, init_beta,
-            jnp.arange(k_max) < n_init,
-            carry.G, carry.rho, carry.gidx,
-            jnp.asarray(h_tilde, jnp.int32),
-            jnp.asarray(h, jnp.int32),
-            pad_mask,
-            loss_name=config.loss, h=h,
-            k_max=k_max, inner_epochs=config.inner_epochs,
-            polish_factor=config.polish_factor,
-            max_outer=config.max_outer,
-            use_seq_ball=use_seq,
-            screen_backend=backend, inner_backend=inner,
-            unpen_idx=-1 if unpen is None else unpen,
-            screen_fn=screen_fn, scan_fn=scan_fn,
-            screen_rule=rule))
-        if not bool(res.overflowed) or k_max >= p_true:
+        # one engine dispatch through the read that waits for it
+        with span("repro.engine.run", b=1, h=h, k_max=k_max):
+            init_idx = init_idx[:k_max]
+            init_beta = init_beta[:k_max]
+            if init_idx.shape[0] < k_max:   # capacity grew after overflow
+                pad = k_max - init_idx.shape[0]
+                init_idx = jnp.pad(init_idx, (0, pad))
+                init_beta = jnp.pad(init_beta, (0, pad))
+            # capacity growth can move the auto crossover (DESIGN.md §6)
+            inner = resolve_inner_backend(config.inner_backend, config.loss,
+                                          n_true, k_max, X.dtype)
+            carry = cold_inner_carry(k_max, X.dtype, backend=inner)
+            # the engine dispatch routes through the fault-injection seam
+            # (repro.runtime.inject) — a single None-check when disarmed
+            res = _fault_seam("serial", lambda: _saif_jit(
+                X, y, col_norm, c0, jnp.asarray(lam, X.dtype),
+                jnp.asarray(config.eps, X.dtype),
+                delta0, init_idx, init_beta,
+                jnp.arange(k_max) < n_init,
+                carry.G, carry.rho, carry.gidx,
+                jnp.asarray(h_tilde, jnp.int32),
+                jnp.asarray(h, jnp.int32),
+                pad_mask,
+                loss_name=config.loss, h=h,
+                k_max=k_max, inner_epochs=config.inner_epochs,
+                polish_factor=config.polish_factor,
+                max_outer=config.max_outer,
+                use_seq_ball=use_seq,
+                screen_backend=backend, inner_backend=inner,
+                unpen_idx=-1 if unpen is None else unpen,
+                screen_fn=screen_fn, scan_fn=scan_fn,
+                screen_rule=rule))
+            with span("repro.sync.overflow"):
+                overflowed = bool(res.overflowed)
+        if not overflowed or k_max >= p_true:
             return res
         k_max = min(2 * k_max, p_true)  # elastic capacity growth + recompile
 

@@ -37,9 +37,15 @@ economics, not numerics:
   nothing then sets another directory in code
   (:func:`enable_compile_cache`).
 
-Module scope imports only stdlib + numpy — ``from repro import
-open_server`` keeps the lazy-surface contract; jax and the engines load
-on first dispatch.
+Module scope imports only stdlib, numpy and the span helper (which loads
+jax on first use) — ``from repro import open_server`` keeps the
+lazy-surface contract; jax and the engines load on first dispatch.
+
+Spans (``repro.runtime.spans``): ``repro.server.submit`` (client thread,
+``req`` = the entry's ``seq``), ``repro.server.coalesce_wait`` (the
+microbatch window held open), ``repro.server.dispatch`` (one per
+microbatch, ``reqs`` = the riders' ``seq``s) and ``repro.server.resolve``
+(host copies, rider views, futures).
 """
 from __future__ import annotations
 
@@ -53,6 +59,8 @@ import time
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
+
+from repro.runtime.spans import read, span
 
 __all__ = ["ServerConfig", "ServerStats", "ServingFuture", "Server",
            "open_server", "enable_compile_cache", "CHECKOUT_CACHE_DIR"]
@@ -109,6 +117,8 @@ class ServerStats(NamedTuple):
     bucket_fallbacks: int        # shapes beyond the configured grid
     stragglers: int              # dispatches flagged by the monitors
     pending: int                 # queued + in-flight right now
+    dispatched: int = 0          # requests taken off the queues
+    queue_wait_s: float = 0.0    # sum of (claim - submit) over them
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +220,7 @@ def _problem_digest(problem, *, design_only: bool = False) -> str:
         if arr is None:
             h.update(b"<none>")
             continue
-        a = np.ascontiguousarray(np.asarray(arr))
+        a = np.ascontiguousarray(read("digest", np.asarray, arr))
         h.update(str(a.shape).encode())
         h.update(str(a.dtype).encode())
         h.update(a.reshape(-1).view(np.uint8))      # no bytes copy
@@ -291,6 +301,8 @@ class Server:
         self._evictions = 0
         self._bucket_fallbacks = 0
         self._stragglers = 0
+        self._dispatched = 0
+        self._queue_wait_s = 0.0
         if self.config.autostart:
             self._start()
 
@@ -348,20 +360,21 @@ class Server:
         with a :class:`ServingFuture`; admission errors raise *here*,
         synchronously, with the same typed taxonomy as the sync path."""
         from repro.core.serving import RequestError, validate_request
-        validate_request(request)
-        with self._cond:
-            if self._stop:
-                raise RequestError("server is closed")
-        key = self._bucket_key(problem, request)
-        fut = ServingFuture()
-        entry = _Entry(next(self._seq),
-                       int(getattr(request, "priority", 0)),
-                       problem, request, fut,
-                       self._coalescible(problem, request))
-        with self._cond:
-            self._submitted += 1
-            self._queues.setdefault(key, []).append(entry)
-            self._cond.notify_all()
+        seq = next(self._seq)
+        with span("repro.server.submit", req=seq):
+            validate_request(request)
+            with self._cond:
+                if self._stop:
+                    raise RequestError("server is closed")
+            key = self._bucket_key(problem, request)
+            fut = ServingFuture()
+            entry = _Entry(seq, int(getattr(request, "priority", 0)),
+                           problem, request, fut,
+                           self._coalescible(problem, request))
+            with self._cond:
+                self._submitted += 1
+                self._queues.setdefault(key, []).append(entry)
+                self._cond.notify_all()
         return fut
 
     def stats(self) -> ServerStats:
@@ -376,7 +389,9 @@ class Server:
                 evictions=self._evictions,
                 bucket_fallbacks=self._bucket_fallbacks,
                 stragglers=self._stragglers,
-                pending=self._pending_locked())
+                pending=self._pending_locked(),
+                dispatched=self._dispatched,
+                queue_wait_s=self._queue_wait_s)
 
     # -- bucketing ------------------------------------------------------
 
@@ -471,18 +486,22 @@ class Server:
         if head.coalesce:
             window = self.config.max_wait_ms / 1e3
             deadline = head.t_submit + window
-            while (not self._stop
-                   and len([e for e in q if e.coalesce])
-                   < self.config.max_batch
-                   and time.monotonic() < deadline):
-                self._cond.wait(max(deadline - time.monotonic(), 1e-4))
+            with span("repro.server.coalesce_wait"):
+                while (not self._stop
+                       and len([e for e in q if e.coalesce])
+                       < self.config.max_batch
+                       and time.monotonic() < deadline):
+                    self._cond.wait(max(deadline - time.monotonic(), 1e-4))
             q = self._queues.get(best_key, [])
             batch = sorted((e for e in q if e.coalesce),
                            key=_rank)[: self.config.max_batch]
         else:
             batch = [head]
+        now = time.monotonic()
         for e in batch:
             q.remove(e)
+            self._queue_wait_s += now - e.t_submit
+        self._dispatched += len(batch)
         if not q:
             self._queues.pop(best_key, None)
         return best_key, batch
@@ -549,6 +568,11 @@ class Server:
         return live
 
     def _dispatch(self, key: tuple, batch: List[_Entry]) -> None:
+        with span("repro.server.dispatch",
+                  reqs=" ".join(str(e.seq) for e in batch), b=len(batch)):
+            self._dispatch_live(key, batch)
+
+    def _dispatch_live(self, key: tuple, batch: List[_Entry]) -> None:
         batch = self._expire_locked(batch)
         if not batch:
             return
@@ -571,7 +595,8 @@ class Server:
         try:
             if len(batch) == 1 and not batch[0].coalesce:
                 res = sess.solve(batch[0].request)
-                batch[0].future._resolve(res)
+                with span("repro.server.resolve"):
+                    batch[0].future._resolve(res)
                 with self._cond:
                     self._served += 1
             else:
@@ -604,7 +629,8 @@ class Server:
         b_pad = _next_pow2(b_real)
         # every rider contributes its OWN response row — the shared
         # design is what the bucket key guarantees
-        Y = np.stack([np.asarray(e.problem.y) for e in batch])
+        Y = np.stack([read("responses", np.asarray, e.problem.y)
+                      for e in batch])
         lams = [float(e.request.lam) for e in batch]
         lams += [lams[0]] * (b_pad - b_real)
         deadlines = [e.request.deadline_s for e in batch
@@ -620,15 +646,16 @@ class Server:
         verdict = res.verdict
         unit_ok = verdict.unit_ok or (verdict.ok,) * b_pad
         unit_deg = verdict.unit_degraded or (False,) * b_pad
-        value_np = _to_host(res.value)   # one transfer per field, then
-        for i, e in enumerate(batch):    # free numpy views per rider
-            v_i = verdict._replace(
-                ok=bool(unit_ok[i]), degraded=bool(unit_deg[i]),
-                unit_ok=(bool(unit_ok[i]),),
-                unit_degraded=(bool(unit_deg[i]),))
-            e.future._resolve(
-                ServingResult(value=_unit_view(value_np, i),
-                              verdict=v_i))
+        with span("repro.server.resolve"):
+            value_np = _to_host(res.value)   # one transfer per field,
+            for i, e in enumerate(batch):    # then numpy views per rider
+                v_i = verdict._replace(
+                    ok=bool(unit_ok[i]), degraded=bool(unit_deg[i]),
+                    unit_ok=(bool(unit_ok[i]),),
+                    unit_degraded=(bool(unit_deg[i]),))
+                e.future._resolve(
+                    ServingResult(value=_unit_view(value_np, i),
+                                  verdict=v_i))
         with self._cond:
             self._served += b_real
             if b_real > 1:
@@ -641,7 +668,8 @@ def _to_host(value):
     once per microbatch so the per-rider slices below are numpy views,
     not per-field device reads."""
     import jax
-    return jax.tree_util.tree_map(np.asarray, value)
+    return jax.tree_util.tree_map(
+        lambda a: read("result", np.asarray, a), value)
 
 
 def _unit_view(value, i: int):

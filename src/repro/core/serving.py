@@ -42,9 +42,14 @@ discipline to every failure mode between the request and the result:
   a SIGTERM'd (``PreemptionGuard``) or restarted server resumes warm
   with zero extra compilations.
 
-Module scope imports only stdlib + numpy: constructing a
-:class:`~repro.core.api.Problem` (which validates here) keeps the lazy
-surface contract of ``repro/__init__.py``.
+Spans (``repro.runtime.spans``): ``repro.serving.solve`` over a whole
+request (retries, ladder), ``repro.serving.certify`` over each
+certification, whose elapsed time is ``Verdict.kkt_check_ms``, and a
+``repro.sync.<what>`` around each device-to-host read of a result.
+
+Module scope imports only stdlib, numpy and the span helper (which loads
+jax on first use): constructing a :class:`~repro.core.api.Problem` (which
+validates here) keeps the lazy surface contract of ``repro/__init__.py``.
 """
 from __future__ import annotations
 
@@ -58,6 +63,8 @@ import warnings
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
+
+from repro.runtime.spans import read, span
 
 __all__ = [
     "ServingError", "RequestError", "NumericalError", "BackendFault",
@@ -567,6 +574,10 @@ class ServingSession:
         failed certificate walks the degradation ladder. Returns
         ``(value, verdict)`` — a typed error (the taxonomy above) is the
         only other way out."""
+        with span("repro.serving.solve"):
+            return self._solve(request, deadline_s)
+
+    def _solve(self, request, deadline_s: Optional[float]) -> ServingResult:
         ser = self.serving
         t0 = time.monotonic()
         if deadline_s is not None:
@@ -795,7 +806,15 @@ class ServingSession:
         """Certify ``value``: finiteness, gap convergence and — where the
         scalar KKT conditions apply — the post-hoc KKT residual. Returns
         ``(ok, converged, gap, kkt, tol, events)`` worst-cased over the
-        request's units (one per lambda / fleet member)."""
+        request's units (one per lambda / fleet member). The whole of it,
+        the host reads included, is the ``repro.serving.certify`` span,
+        and its elapsed time the ``kkt_check_ms`` counters."""
+        with span("repro.serving.certify") as sp:
+            out = self._certify(request, value, sess)
+        self._kkt_ms += sp.elapsed_s * 1e3
+        return out
+
+    def _certify(self, request, value, sess=None):
         sess = self.session if sess is None else sess
         ser = self.serving
         import jax.numpy as jnp
@@ -806,10 +825,10 @@ class ServingSession:
         ok, converged = True, True
         gap_w, kkt_w, tol_w = 0.0, 0.0, 0.0
         unit_ok: List[bool] = []
-        t_k0 = time.perf_counter()
         for u in units:
-            finite = bool(np.all(np.isfinite(np.asarray(u["beta"]))))
-            g = float(u["gap"])
+            finite = bool(np.all(np.isfinite(
+                read("beta", np.asarray, u["beta"]))))
+            g = read("gap", float, u["gap"])
             finite = finite and math.isfinite(g)
             u_ok = finite
             if not finite:
@@ -837,7 +856,8 @@ class ServingSession:
                 if u.get("kkt_r") is not None:   # batched fleet cert
                     r = u["kkt_r"]
                 else:
-                    r = float(_kkt_fn(sess.config.loss)(
+                    r = read("certificate", float, _kkt_fn(
+                        sess.config.loss)(
                         X, u["y"], _beside(u["beta"], X),
                         jnp.asarray(lam, X.dtype), u["pen"],
                         u["sample_w"]))
@@ -851,7 +871,6 @@ class ServingSession:
                 u_ok = u_ok and (g <= eps)
             ok = ok and u_ok
             unit_ok.append(u_ok)
-        self._kkt_ms += (time.perf_counter() - t_k0) * 1e3
         self._last_unit_ok = unit_ok
         return ok, converged, gap_w, kkt_w, tol_w, events
 
@@ -880,27 +899,30 @@ class ServingSession:
                 # group KKT is blockwise; certify by gap only
                 return [dict(beta=value.beta, gap=value.gap,
                              lam=request.lam, kkt=False,
-                             n_outer=int(value.n_outer))]
+                             n_outer=read("n_outer", int, value.n_outer))]
             X, y, pen = design()
             res = value[1] if fusedp else value
             sw = None if sess.problem.weights is None \
                 else jnp.asarray(sess.problem.weights, X.dtype)
             return [dict(beta=res.beta, gap=res.gap, lam=request.lam,
                          kkt=True, X=X, y=y, pen=pen, sample_w=sw,
-                         overflowed=bool(res.overflowed),
-                         n_outer=int(res.n_outer))]
+                         overflowed=read("overflowed", bool,
+                                         res.overflowed),
+                         n_outer=read("n_outer", int, res.n_outer))]
 
         if isinstance(request, api.Path):
             if grouped:
                 return [dict(beta=r.beta, gap=r.gap, lam=float(lam),
-                             kkt=False, n_outer=int(r.n_outer))
+                             kkt=False,
+                             n_outer=read("n_outer", int, r.n_outer))
                         for lam, r in zip(value.lams, value.results)]
             X, y, pen = design()
             pr = value.path if fusedp else value
             return [dict(beta=b, gap=r.gap, lam=float(lam), kkt=True,
                          X=X, y=y, pen=pen, sample_w=None,
-                         overflowed=bool(r.overflowed),
-                         n_outer=int(r.n_outer))
+                         overflowed=read("overflowed", bool,
+                                         r.overflowed),
+                         n_outer=read("n_outer", int, r.n_outer))
                     for lam, b, r in zip(pr.lams, pr.betas, pr.results)]
 
         if isinstance(request, api.Fleet):
@@ -919,16 +941,17 @@ class ServingSession:
             # one host transfer per batched field, then free numpy
             # slicing — per-unit device reads would cost a dispatch +
             # sync each and dominate wide coalesced batches
-            beta = np.asarray(value.beta)
-            gap = np.asarray(value.gap)
-            ovf = np.asarray(value.overflowed)
-            nout = np.asarray(value.n_outer)
+            beta = read("beta", np.asarray, value.beta)
+            gap = read("gap", np.asarray, value.gap)
+            ovf = read("overflowed", np.asarray, value.overflowed)
+            nout = read("n_outer", np.asarray, value.n_outer)
             kkt_r = None
             if self.serving.check_kkt and W is None:
-                kkt_r = np.asarray(_kkt_fleet_fn(sess.config.loss)(
-                    X, Y, value.beta,
-                    jnp.asarray(lams, X.dtype), pen))
-            Y_np = np.asarray(Y)    # host y slices for the fallback path
+                kkt_r = read("certificate", np.asarray, _kkt_fleet_fn(
+                    sess.config.loss)(X, Y, value.beta,
+                                      jnp.asarray(lams, X.dtype), pen))
+            # host y slices for the fallback path
+            Y_np = read("responses", np.asarray, Y)
             return [dict(beta=beta[b], gap=gap[b],
                          lam=float(lams[b]), kkt=True, X=X, y=Y_np[b],
                          pen=pen,
@@ -943,7 +966,8 @@ class ServingSession:
             X, y, pen = design()
             if value.beta is None:
                 # scores-only CV: certify the score table's finiteness
-                return [dict(beta=jnp.asarray(np.asarray(value.cv_mean)),
+                return [dict(beta=jnp.asarray(read("result", np.asarray,
+                                                value.cv_mean)),
                              gap=0.0, lam=float(value.best_lam),
                              kkt=False)]
             res = value.best_result
@@ -952,8 +976,9 @@ class ServingSession:
                          lam=float(value.best_lam), kkt=True, X=X, y=y,
                          pen=pen, sample_w=None,
                          overflowed=False if res is None
-                         else bool(res.overflowed),
-                         n_outer=0 if res is None else int(res.n_outer))]
+                         else read("overflowed", bool, res.overflowed),
+                         n_outer=0 if res is None
+                         else read("n_outer", int, res.n_outer))]
 
         if isinstance(request, api.Update):
             if value is None:        # resolve=False: ingest-only, nothing
@@ -966,14 +991,16 @@ class ServingSession:
             return [dict(beta=value.beta, gap=value.gap,
                          lam=float(lam), kkt=True, X=prep.X, y=prep.y,
                          pen=None, sample_w=None,
-                         overflowed=bool(value.overflowed),
-                         n_outer=int(value.n_outer))]
+                         overflowed=read("overflowed", bool,
+                                         value.overflowed),
+                         n_outer=read("n_outer", int, value.n_outer))]
 
         if isinstance(request, api.Select):
             if value.beta is None:
                 # no refit requested: certify the CV score table's
                 # finiteness at the chosen lambda (the CV idiom above)
-                return [dict(beta=jnp.asarray(np.asarray(value.cv_mean)),
+                return [dict(beta=jnp.asarray(read("result", np.asarray,
+                                                value.cv_mean)),
                              gap=0.0, lam=float(value.lam), kkt=False)]
             if getattr(sess, "_online", None) is not None:
                 prep = sess._prep
@@ -986,8 +1013,9 @@ class ServingSession:
                          lam=float(value.lam), kkt=True, X=X, y=y,
                          pen=pen, sample_w=None,
                          overflowed=False if res is None
-                         else bool(res.overflowed),
-                         n_outer=0 if res is None else int(res.n_outer))]
+                         else read("overflowed", bool, res.overflowed),
+                         n_outer=0 if res is None
+                         else read("n_outer", int, res.n_outer))]
 
         raise RequestError(f"unknown request {request!r}")
 

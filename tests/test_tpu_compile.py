@@ -11,6 +11,9 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,6 +24,9 @@ from repro.kernels.screen.screen import (screen_fused_batch_pallas,
                                          screen_fused_pallas,
                                          ub_histogram_batch_pallas,
                                          ub_histogram_pallas)
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+from bench import trace  # noqa: E402
 
 N, P, H, B, K = 1024, 1 << 20, 32, 8, 256
 P_CHAIN = 1 << 14
@@ -116,3 +122,28 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     # beside the operands, a kernel's temporaries must stay within what
     # one v5e core can hold: no design-sized copy of X for the screen
     assert mem.temp_size_in_bytes < V5E_VMEM_BYTES
+
+
+# the kernels the benchmark's per-layer metrics find by instruction name
+# (bench/metrics/screen_*.py, cm_ms_per_solution.py), each compiled as
+# the engines run it: inside an outer jit, under a named scope
+SCOPED = {"screen_fused_batch_pallas": ("screen", "screen_fused_batch"),
+          "cm_burst_batch_pallas": ("cm", "cm_burst_batch_logistic")}
+
+
+@pytest.mark.parametrize("kernel", sorted(SCOPED))
+def test_named_scopes_keep_the_kernel_instruction_name(one_chip, kernel):
+    scope, name = SCOPED[kernel]
+    fn, specs = KERNELS[name]
+
+    def scoped(*a):
+        with jax.named_scope(scope):
+            return fn(*a)
+
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in specs]
+    text = jax.jit(scoped).lower(*args).compile().as_text()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    assert {trace.kernel_base(c) for c in calls} == {kernel}
