@@ -142,6 +142,13 @@ def add_features(aset: ActiveSet, cand_idx: jax.Array,
       cand_idx:  int32 (h,) candidate feature ids (descending score order).
       cand_keep: bool  (h,) which candidates to actually add.
     """
+    return add_features_to_slots(aset, cand_idx, cand_keep)[0]
+
+
+def add_features_to_slots(aset: ActiveSet, cand_idx: jax.Array,
+                          cand_keep: jax.Array):
+    """:func:`add_features`, also returning each candidate's slot: (h,)
+    int32, ``k_max`` for a candidate that was not placed."""
     k_max = aset.mask.shape[0]
     h = cand_idx.shape[0]
     free = ~aset.mask                                   # (k_max,)
@@ -172,7 +179,7 @@ def add_features(aset: ActiveSet, cand_idx: jax.Array,
     return ActiveSet(new_idx, new_mask, new_beta, new_in_active,
                      overflowed=aset.overflowed | (n_want > n_free),
                      order=compact_order(aset.order, new_mask),
-                     count=aset.count + n_placed)
+                     count=aset.count + n_placed), target_slot
 
 
 def scatter_beta(aset: ActiveSet, p: int) -> jax.Array:
@@ -214,7 +221,7 @@ def gather_columns_batch(X: jax.Array, aset: ActiveSet) -> jax.Array:
 
 
 delete_features_batch = jax.vmap(delete_features)
-add_features_batch = jax.vmap(add_features)
+add_features_to_slots_batch = jax.vmap(add_features_to_slots)
 
 
 def scatter_beta_batch(aset: ActiveSet, p: int) -> jax.Array:

@@ -90,6 +90,10 @@ class _BatchState(NamedTuple):
     trace_screened: jax.Array   # (B, max_outer) int32 observability (ISSUE 9)
     trace_survivors: jax.Array
     trace_post_viol: jax.Array
+    # (B, n, k_max) active blocks of the pallas fleet step, carried across
+    # outer steps: live slots hold their feature's column of X, dead slots
+    # are stale and zeroed on read. None on the map-fused paths.
+    block: Optional[jax.Array] = None
 
 
 def _freeze_select(live: jax.Array, old, new):
@@ -107,6 +111,27 @@ def _n_surv32_batch(out: ScreenOut, b: int) -> jax.Array:
     if ns is None:
         return jnp.zeros((b,), jnp.int32)
     return jnp.broadcast_to(ns.astype(jnp.int32), (b,))
+
+
+def _fetches_columns(screen_backend: str,
+                     screen_fn: Optional[BatchScreenFn]) -> bool:
+    """Does the engine fetch recruited columns with the fetch kernel?
+
+    Yes when the screen is the compiled Pallas kernel, which streams
+    row-major tiles of X: a minor-axis gather (``jnp.take(X, ids,
+    axis=1)``) would make XLA hold X column-major and lay the whole design
+    out again for the screen at every ADD phase. Elsewhere (the CPU, the
+    interpreter, the XLA screens, a custom ``screen_fn``) ``jnp.take``
+    costs no such copy. Either way each value is a copy of the column."""
+    from repro.kernels.screen import screen as screen_kernels
+    return (screen_fn is None and screen_backend == "pallas"
+            and not screen_kernels.default_interpret())
+
+
+def _read_block(block: jax.Array, aset: aset_lib.ActiveSet) -> jax.Array:
+    """The carried (B, n, k_max) active blocks as
+    ``active_set.gather_columns_batch`` gives them: dead slots zeroed."""
+    return jnp.where(aset.mask[:, None, :], block, 0.0)
 
 
 @partial(jax.jit, static_argnames=("loss_name", "h", "k_max",
@@ -144,6 +169,28 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         screen = make_batch_screen(screen_backend, X, col_norm, h)
     inner = make_batch_inner(inner_backend, loss, X, Y, col_norm, h,
                              weights=weights)
+    # The pallas fleet step carries the active blocks (``_BatchState.
+    # block``): filled once here, DEL only changes the mask, ADD writes
+    # the slots it fills.
+    carried = inner.fleet_step is not None
+    fetch = _fetches_columns(screen_backend, screen_fn)
+
+    def take_rows(ids, placed):
+        """(..., n) rows: X's columns ``ids`` where ``placed``."""
+        if fetch:
+            from repro.kernels.screen.fetch import fetch_columns_pallas
+            rows = fetch_columns_pallas(X, ids.reshape(-1),
+                                        placed.reshape(-1))
+            return rows.reshape(ids.shape + (n,))
+        return jnp.moveaxis(jnp.take(X, ids, axis=1), 0, -1)
+
+    def place(block, ids, slot):
+        """Write X's columns ``ids`` into the (B, n, k_max) blocks at
+        ``slot`` ((B, h); k_max = not placed)."""
+        with jax.named_scope("add_delete"):
+            rows = take_rows(ids, slot < k_max)
+            return block.at[barange[:, None], :, slot].set(rows,
+                                                           mode="drop")
 
     aset0 = aset_lib.init_active_set_batch(p, k_max, init_idx, X.dtype,
                                            init_beta, live_mask=init_mask)
@@ -153,8 +200,13 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         # never scored; see the serial engine's identical guard
         aset0 = aset0._replace(in_active=aset0.in_active | pad_mask[None, :])
     carry_in = InnerCarry(G=init_G, rho=init_rho, gidx=init_gidx)
-    inner0 = inner.init(aset0, carry_in,
-                        aset_lib.gather_columns_batch(X, aset0))
+    if carried:
+        block0 = jnp.swapaxes(take_rows(aset0.idx, aset0.mask), 1, 2)
+        Xa0 = _read_block(block0, aset0)
+    else:
+        block0 = None
+        Xa0 = aset_lib.gather_columns_batch(X, aset0)
+    inner0 = inner.init(aset0, carry_in, Xa0)
     trace0 = jnp.full((b, max_outer), -1.0, X.dtype)
     itrace0 = jnp.full((b, max_outer), -1, jnp.int32)
     state0 = _BatchState(
@@ -165,7 +217,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         t=jnp.zeros((b,), jnp.int32), inner=inner0,
         trace_n_active=trace0, trace_gap=trace0,
         trace_screened=itrace0, trace_survivors=itrace0,
-        trace_post_viol=itrace0)
+        trace_post_viol=itrace0, block=block0)
     # per-problem serial Newton polish (hybrid rule): rides inside the
     # map-fused live branch so each problem's arithmetic is the literal
     # serial newton_step — the parity contract extends to the hybrid rule
@@ -294,11 +346,12 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
              r_del) = jax.lax.map(solve_one, xs)
         else:
             # --- fleet-step path (the pallas problem-gridded kernel): the
-            # backend owns the whole fleet's bursts in one launch, then
-            # the per-problem certificate map runs (liveness-gated,
-            # gathering each live problem's block like the serial body).
+            # backend owns the whole fleet's bursts in one launch on the
+            # carried blocks, then the per-problem certificate map runs
+            # on the same blocks (liveness-gated, like the serial body).
+            Xa = _read_block(s.block, aset)
             with jax.named_scope("cm"):
-                out, inner_carry = inner.fleet_step(s.inner, aset, lam,
+                out, inner_carry = inner.fleet_step(s.inner, aset, Xa, lam,
                                                     n_ep)
             beta = jnp.where(live[:, None], out.beta, aset.beta)
             z = jnp.where(live[:, None], out.z, s.z)
@@ -308,14 +361,13 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             def certify_one(args):
                 if has_weights:
                     (live_b, y_b, w_b, theta_b, gap_b, lam_b, eps_b,
-                     delta_b, is_add_b, aset_b, cn_b, c0_b) = args
+                     delta_b, is_add_b, aset_b, Xa_b, cn_b, c0_b) = args
                 else:
                     (live_b, y_b, theta_b, gap_b, lam_b, eps_b, delta_b,
-                     is_add_b, aset_b, cn_b, c0_b) = args
+                     is_add_b, aset_b, Xa_b, cn_b, c0_b) = args
                     w_b = None
 
                 def live_branch(_):
-                    Xa_b = aset_lib.gather_columns(X, aset_b)
                     return _certify(y_b, w_b, theta_b, gap_b, lam_b,
                                     eps_b, delta_b, is_add_b, Xa_b,
                                     aset_b.idx, aset_b.mask, cn_b, c0_b)
@@ -330,7 +382,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                                     None)
 
             xs = (live, Y, theta, gap, lam, eps, s.delta, s.is_add,
-                  aset, col_norm, c0)
+                  aset, Xa, col_norm, c0)
             if has_weights:
                 xs = (live, Y, weights) + xs[2:]
             with jax.named_scope("gap"):
@@ -355,7 +407,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             do_add = live & s.is_add & ~stop_now
 
         def do_add_phase(args):
-            aset, delta, is_add = args
+            aset, block, delta, is_add = args
             with jax.named_scope("screen"):
                 out: ScreenOut = screen(theta_c, r_eff, aset.in_active,
                                         do_add)
@@ -378,8 +430,10 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             keep = keep | _stuck_recruits(out, col_norm, r_eff, gap, eps)
             adding = do_add & ~add_done
             with jax.named_scope("add_delete"):
-                aset = aset_lib.add_features_batch(aset, out.cand_idx,
-                                                   keep & adding[:, None])
+                aset, slot = aset_lib.add_features_to_slots_batch(
+                    aset, out.cand_idx, keep & adding[:, None])
+            if carried:
+                block = place(block, out.cand_idx, slot)
             done = do_add & add_done
             if screen_rule.delta_ramp:
                 grown = jnp.minimum(10.0 * delta, 1.0)
@@ -389,15 +443,15 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
             else:
                 new_delta = delta
                 new_is_add = jnp.where(done, False, is_add)
-            return (aset, new_delta, new_is_add,
+            return (aset, block, new_delta, new_is_add,
                     jnp.where(do_add, n_scr_scr, -1),
                     jnp.where(do_add, n_sur_scr, -1))
 
         neg1 = jnp.full((b,), -1, jnp.int32)
-        aset, delta, is_add, n_scr, n_sur = jax.lax.cond(
+        aset, block, delta, is_add, n_scr, n_sur = jax.lax.cond(
             jnp.any(do_add), do_add_phase,
             lambda a: a + (neg1, neg1),
-            (aset, s.delta, s.is_add))
+            (aset, s.block, s.delta, s.is_add))
 
         # --- safe post-check (hybrid rule, DESIGN.md §13) -----------------
         # one full screen at the unshrunk safe radius gates every stop;
@@ -406,7 +460,8 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         if screen_rule.post_check:
             do_check = live & stop_now
 
-            def check(a):
+            def check(args):
+                a, block = args
                 with jax.named_scope("screen"):
                     chk: ScreenOut = screen(theta_c, r_del, a.in_active,
                                             do_check)
@@ -419,14 +474,18 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
                 keep = keep.at[:, 0].set(
                     viol & jnp.isfinite(chk.cand_score[:, 0]))
                 with jax.named_scope("add_delete"):
-                    a = aset_lib.add_features_batch(a, chk.cand_idx, keep)
-                return a, jnp.where(do_check, viol.astype(jnp.int32), -1)
+                    a, slot = aset_lib.add_features_to_slots_batch(
+                        a, chk.cand_idx, keep)
+                if carried:
+                    block = place(block, chk.cand_idx, slot)
+                return (a, block,
+                        jnp.where(do_check, viol.astype(jnp.int32), -1))
 
-            def no_check(a):
-                return a, neg1
+            def no_check(args):
+                return args + (neg1,)
 
-            aset, post_viol = jax.lax.cond(jnp.any(do_check), check,
-                                           no_check, aset)
+            aset, block, post_viol = jax.lax.cond(
+                jnp.any(do_check), check, no_check, (aset, block))
             stop_final = stop_now & (post_viol != 1)
         else:
             post_viol = neg1
@@ -435,7 +494,7 @@ def _saif_batch_jit(X, Y, W, col_norm, c0, lam, eps, delta0, init_idx,
         n_act = aset.count.astype(X.dtype)
         new = _BatchState(
             aset=aset, z=z, gap=gap, delta=delta, is_add=is_add,
-            stop=stop_final, t=s.t + 1, inner=inner_carry,
+            stop=stop_final, t=s.t + 1, inner=inner_carry, block=block,
             trace_n_active=s.trace_n_active.at[barange, s.t].set(
                 n_act, mode="drop"),
             trace_gap=s.trace_gap.at[barange, s.t].set(gap, mode="drop"),

@@ -341,9 +341,10 @@ class BatchInnerBackend(NamedTuple):
         gathers the active block, refreshes and runs the SERIAL backend
         built here, all under a per-problem liveness ``lax.cond`` — a
         frozen problem costs literally nothing per outer step.
-      * ``fleet_step(carry, aset, lam, n_ep) -> (InnerOut, carry)`` — the
-        *gridded-kernel* path (pallas): gathers its own fleet blocks and
-        runs one problem-gridded launch for every burst; frozen problems
+      * ``fleet_step(carry, aset, Xa, lam, n_ep) -> (InnerOut, carry)``
+        — the *gridded-kernel* path (pallas): one problem-gridded launch
+        for every burst on the fleet's (B, n, k_max) active blocks ``Xa``,
+        which the engine carries across outer steps; frozen problems
         ride along with zero-trip epoch loops (cheap, not free — the
         kernel still runs their z/dual tail).
 
@@ -414,7 +415,7 @@ def make_batch_inner_gram(loss: Loss, X: jax.Array, Y: jax.Array,
                              make_one=make_one)
 
 
-def make_batch_inner_pallas(loss: Loss, X: jax.Array, Y: jax.Array,
+def make_batch_inner_pallas(loss: Loss, Y: jax.Array,
                             col_norm: jax.Array,
                             interpret: bool | None = None,
                             weights=None) -> BatchInnerBackend:
@@ -427,8 +428,7 @@ def make_batch_inner_pallas(loss: Loss, X: jax.Array, Y: jax.Array,
                          "sample weights; use 'jnp' or 'gram' for CV "
                          "fleets (DESIGN.md §8)")
 
-    def fleet_step(carry, aset, lam, n_ep):
-        Xa = aset_lib.gather_columns_batch(X, aset)
+    def fleet_step(carry, aset, Xa, lam, n_ep):
         # col_norm is the fleet (B, p) matrix (shared designs broadcast it)
         norms = jnp.where(aset.mask,
                           jnp.take_along_axis(col_norm, aset.idx, axis=1),
@@ -451,7 +451,7 @@ def make_batch_inner(name: str, loss: Loss, X: jax.Array, Y: jax.Array,
     if name == "gram":
         return make_batch_inner_gram(loss, X, Y, h, weights=weights)
     if name == "pallas":
-        return make_batch_inner_pallas(loss, X, Y, col_norm,
+        return make_batch_inner_pallas(loss, Y, col_norm,
                                        weights=weights)
     return make_batch_inner_jnp(loss, X, Y, weights=weights)
 

@@ -5,21 +5,29 @@ described, not attached: no kernel executes, but every layout, lowering and
 VMEM refusal the chip would raise is raised here, at deployment widths
 (n = 1024 samples, p = 2^20 features, h = 32 candidates per tile, k = 256
 active slots, fleets of 8). These are the kernels the ``auto`` policies
-pick on TPU (DESIGN.md §3, §6, §7).
+pick on TPU (DESIGN.md §3, §6, §7). The fleet engine itself is compiled
+at the benchmark cells' shapes too: X must stay row-major inside it, with
+no design-sized copy beside the parameter.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import pathlib
+import re
 import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.batch import _saif_batch_jit
+from repro.kernels.cm import cm as cm_kernels
 from repro.kernels.cm.cm import cm_burst_batch_pallas, cm_burst_pallas
 from repro.kernels.fused.fused import chain_suffix_sums_pallas
+from repro.kernels.screen import fetch as fetch_kernels
+from repro.kernels.screen import screen as screen_kernels
+from repro.kernels.screen.fetch import fetch_columns_pallas
 from repro.kernels.screen.screen import (screen_fused_batch_pallas,
                                          screen_fused_pallas,
                                          ub_histogram_batch_pallas,
@@ -84,6 +92,10 @@ KERNELS = {
     "chain_suffix_sums": (
         lambda X: chain_suffix_sums_pallas(X, interpret=False),
         [f32(N, P_CHAIN)]),
+    "fetch_columns": (
+        lambda X, ids, placed: fetch_columns_pallas(X, ids, placed,
+                                                    interpret=False),
+        [f32(N, P), i32(B * H), bool_(B * H)]),
 }
 
 
@@ -128,7 +140,8 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 # (bench/metrics/screen_*.py, cm_ms_per_solution.py), each compiled as
 # the engines run it: inside an outer jit, under a named scope
 SCOPED = {"screen_fused_batch_pallas": ("screen", "screen_fused_batch"),
-          "cm_burst_batch_pallas": ("cm", "cm_burst_batch_logistic")}
+          "cm_burst_batch_pallas": ("cm", "cm_burst_batch_logistic"),
+          "fetch_columns_pallas": ("add_delete", "fetch_columns")}
 
 
 @pytest.mark.parametrize("kernel", sorted(SCOPED))
@@ -147,3 +160,58 @@ def test_named_scopes_keep_the_kernel_instruction_name(one_chip, kernel):
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls
     assert {trace.kernel_base(c) for c in calls} == {kernel}
+
+
+# the fleet engine as the benchmark cells run it: (loss, n, p, B) with the
+# compiled Pallas screen and CM burst, k_max = 1024 slots
+ENGINES = {"least_squares_b1": ("least_squares", N, P, 1),
+           "least_squares_b8": ("least_squares", N, P, B),
+           "logistic_b1": ("logistic", N, 1 << 18, 1)}
+K_ENGINE = 1024
+# an instruction whose result is the design: its opcode and layout
+_DESIGN_OP = r"%[\w.-]+ = f32\[{n},{p}\]\{{([^}}]*)\}} ([\w-]+)\("
+
+
+@pytest.fixture(scope="module", params=sorted(ENGINES))
+def engine(one_chip, request):
+    """``_saif_batch_jit`` compiled for the described chip. Off the chip
+    the kernels resolve to the interpreter; here they are steered to
+    Mosaic as on the chip."""
+    loss, n, p, b = ENGINES[request.param]
+    k = K_ENGINE
+
+    def arg(dt, *shape):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f, i = jnp.float32, jnp.int32
+    args = (arg(f, n, p), arg(f, b, n), arg(f, 1, 1), arg(f, b, p),
+            arg(f, b, p), arg(f, b), arg(f, b), arg(f, b), arg(i, b, k),
+            arg(f, b, k), arg(jnp.bool_, b, k), arg(f, b, 1, 1),
+            arg(f, b, 1), arg(i, b, 1), arg(i, b), arg(i, b))
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (screen_kernels, cm_kernels, fetch_kernels):
+            mp.setattr(mod, "default_interpret", lambda: False)
+        compiled = _saif_batch_jit.lower(
+            *args, loss_name=loss, h=H, k_max=k, inner_epochs=5,
+            polish_factor=8, max_outer=2000, use_seq_ball=True,
+            screen_backend="pallas", inner_backend="pallas").compile()
+    return (n, p), compiled
+
+
+def test_engine_keeps_the_design_row_major(engine):
+    """No copy, transpose or gather relayout of X: every instruction
+    whose result is the design aliases the parameter, row-major."""
+    (n, p), compiled = engine
+    text = compiled.as_text()
+    found = re.findall(_DESIGN_OP.format(n=n, p=p), text)
+    assert found
+    for layout, opcode in found:
+        assert opcode in ("parameter", "get-tuple-element", "bitcast"), (
+            opcode, layout)
+        assert layout.startswith("1,0"), (opcode, layout)
+    assert "fetch_columns_pallas" in text
+
+
+def test_engine_temporaries_are_a_fraction_of_the_design(engine):
+    (n, p), compiled = engine
+    assert compiled.memory_analysis().temp_size_in_bytes < n * p * 4 // 8
