@@ -2,6 +2,10 @@
 set-up, window, trace reduction and the comparison that decides
 ``correct``; and the faults and the control that comparison must catch.
 
+The cells are the ``workloads`` of ``BENCHMARK.json``, read when the
+tests are collected: a cell added there, with its files, is rehearsed
+with no edit here.
+
 The harness runs in a child process (``JAX_PLATFORMS=cpu``, x64 off, the
 Pallas kernels in interpret mode), as the benchmark does; the scenarios
 share one child so the engine compiles once.
@@ -15,15 +19,36 @@ import sys
 
 import pytest
 
+from bench import cell
+
 ROOT = pathlib.Path(__file__).resolve().parents[2]
-CELLS = ("sim1m.scalar", "logit256k.scalar", "sim1m.coalesced8")
 DEVICE_METRICS = ("device_idle_share", "screen_roofline",
                   "screen_ms_per_solution", "cm_ms_per_solution")
-N = 64
-# the configurations' gap target (16x the float32 floor) at n = 64
-TINY = {"design": {"n": N, "p": 1024},
-        "solver": {"eps": 2.0 ** -7 * N / 1024, "screen_backend": "pallas",
-                   "inner_backend": "pallas"}}
+# one cell is rehearsed with the profiler off, so the untraced result line
+# (the end-to-end metrics) is seen too; every other cell is traced
+UNTRACED = ("logit256k.scalar",)
+N, P = 64, 1024
+
+
+def cells(root: pathlib.Path = ROOT) -> tuple:
+    """The names of the benchmark's cells, in the order it lists them."""
+    bm = json.loads((root / "BENCHMARK.json").read_text())
+    return tuple(w["name"] for w in bm["workloads"])
+
+
+CELLS = cells()
+
+
+def tiny(config: dict) -> dict:
+    """Overrides that shrink a configuration to n = N, p = P: its gap
+    target scaled with n, as the configuration scales it (a multiple of
+    the float32 floor, which grows with n), and the Pallas kernels forced
+    so their bodies run in interpret mode."""
+    eps = float(config["solver"]["eps"]) * N / int(config["design"]["n"])
+    return {"design": {"n": N, "p": P},
+            "solver": {"eps": eps, "screen_backend": "pallas",
+                       "inner_backend": "pallas"}}
+
 
 SCRIPT = r"""
 import json, pathlib, sys
@@ -31,7 +56,7 @@ sys.path.insert(0, @ROOT@)
 import numpy as np
 from bench import cell, control
 
-TINY = json.loads(@TINY@)
+TINY, UNTRACED = json.loads(@ARGS@)   # {cell: overrides}, [cell]
 SEED = 2**31 + 7          # more than 32 signed bits hold
 
 
@@ -41,13 +66,12 @@ def emit(name, out, **extra):
 
 def run(name, trace=False, keep=None):
     return cell.run(name, SEED, 1.0, trace, require_chip=False,
-                    overrides=TINY, keep=keep)
+                    overrides=TINY[name], keep=keep)
 
 
-for name, trace in (("sim1m.scalar", True), ("logit256k.scalar", False),
-                    ("sim1m.coalesced8", True)):
+for name in TINY:
     keep = {}
-    emit(name, run(name, trace=trace, keep=keep))
+    emit(name, run(name, trace=name not in UNTRACED, keep=keep))
     ok, checks = control.judge_control(keep)
     emit("control." + name, None, control_correct=ok, control=checks)
 
@@ -95,8 +119,10 @@ def rehearsal(tmp_path_factory):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(cache))
     env.pop("JAX_ENABLE_X64", None)
+    args = json.dumps([{c: tiny(cell.cell_spec(c).config) for c in CELLS},
+                       list(UNTRACED)])
     script = SCRIPT.replace("@ROOT@", repr(str(ROOT))).replace(
-        "@TINY@", repr(json.dumps(TINY)))
+        "@ARGS@", repr(args))
     p = subprocess.run([sys.executable, "-c", script], cwd=ROOT, env=env,
                        capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-4000:]
@@ -124,6 +150,21 @@ def test_cpu_rehearsal_reports_no_device_metric(rehearsal, name):
     assert not set(DEVICE_METRICS) & set(out["metrics"])
     assert "breakdown" not in out
     assert "busy_s" not in out["device"]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_rehearsal_reports_what_the_cpu_can_read(rehearsal, name):
+    """Untraced: every end-to-end metric of the cell. Traced: every
+    per-layer metric of the cell that the program counts, as the chip's
+    traced run reports it."""
+    spec = cell.cell_spec(name)
+    m = rehearsal[name]["out"]["metrics"]
+    if name in UNTRACED:
+        assert set(m) == {e["name"] for e in spec.end_to_end}
+    else:
+        counted = {e["name"] for e in spec.per_layer
+                   if e["source"] == "program_counter"}
+        assert counted and counted <= set(m)
 
 
 def test_traced_rehearsal_reads_program_counters(rehearsal):
@@ -166,9 +207,84 @@ def test_control_of_each_cell_is_judged_not_correct(rehearsal, name):
     assert failed and set(failed) <= {"kkt_rel", "coef_err"}
 
 
-def test_benchmark_lists_every_rehearsed_cell():
-    bm = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert [w["name"] for w in bm["workloads"]] == list(CELLS)
+@pytest.mark.parametrize("name", CELLS)
+def test_workload_resolves_by_files_alone(name):
+    """Its configuration, traffic, kind, loop, responses, design, limits
+    and the reader of every metric that applies to it are found by the
+    names ``BENCHMARK.json`` and the data files give."""
+    import bench
+    from bench import correct, traffic
+    spec = cell.cell_spec(name)
+    assert bench.find("designs", spec.config["design"]["generator"])
+    kind = traffic.kind(spec.mix)
+    assert callable(kind.client) and callable(kind.warmup)
+    assert callable(kind.answers)
+    assert callable(traffic.loop(spec.mix).drive)
+    assert callable(bench.find("responses", spec.mix["responses"]["kind"])
+                    .make)
+    assert set(spec.limits) == set(correct.NUMBERS)
+    assert spec.end_to_end and spec.per_layer
+    for m in spec.end_to_end + spec.per_layer:
+        assert callable(bench.find("metrics", m["name"]).read)
+
+
+ADDED = {"name": "logit256k.lam80", "config": "logit-n1k-p256k",
+         "traffic": "closed1-pool4-lam80-50-30", "chips": 1,
+         "why": "a cell made of files that are already there"}
+
+ADD_SCRIPT = r"""
+import json, pathlib, sys
+root = pathlib.Path.cwd()
+sys.path.insert(0, str(root))
+sys.path.insert(0, str(root / "bench" / "tests"))
+from bench import cell
+import test_bench_rehearsal as rehearsal
+name = sys.argv[1]
+spec = cell.cell_spec(name, root)
+assert name in rehearsal.cells(root), rehearsal.cells(root)
+out = cell.run(name, 3, 0.5, False, root=root, require_chip=False,
+               overrides=rehearsal.tiny(spec.config))
+print(json.dumps({"bench": cell.__file__, "config": spec.config["name"],
+                  "traffic": spec.mix["kind"],
+                  "per_layer": [m["name"] for m in spec.per_layer],
+                  "out": out}))
+"""
+
+
+def test_a_cell_added_by_files_alone_is_found_and_rehearsed(tmp_path):
+    """Copy the benchmark, add one workload from a configuration and a
+    traffic mix that are there (new entries, and a limits file of the
+    new cell's name), and edit nothing: ``cell.cell_spec`` and the
+    rehearsal's list of cells take it up, and it runs correct."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    name = ADDED["name"]
+    bm = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    assert name not in cells(tmp_path)
+    bm["workloads"].append(ADDED)
+    for m in bm["per_layer"]:
+        if "logit256k.scalar" in m.get("workloads", ()):
+            m["workloads"].append(name)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm, indent=1))
+    shutil.copy(tmp_path / "bench" / "limits" / "logit256k.scalar.json",
+                tmp_path / "bench" / "limits" / f"{name}.json")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    env.pop("JAX_ENABLE_X64", None)
+    p = subprocess.run([sys.executable, "-c", ADD_SCRIPT, name],
+                       cwd=tmp_path, env=env, capture_output=True,
+                       text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    got = json.loads(p.stdout.splitlines()[-1])
+    assert pathlib.Path(got["bench"]).is_relative_to(tmp_path.resolve())
+    assert got["config"] == "logit-n1k-p256k" and got["traffic"] == "scalar"
+    assert "outer_steps_per_solution" in got["per_layer"]
+    out = got["out"]
+    assert out["correct"], out["checks"]
+    assert set(out["metrics"]) == {"solutions_per_s", "latency_p50_s",
+                                   "setup_s"}
 
 
 def _cli(cwd, env):
